@@ -5,8 +5,12 @@ Features exercised end-to-end (examples/pretrain_c4_style.py):
   * gradient accumulation (TrainConfig.microbatch)
   * checkpoint every N steps (async, atomic) + auto-resume from latest
   * preemption hook: touch <ckpt_root>/PREEMPT to force save-and-exit
-  * straggler watchdog: EMA step time; logs slow steps (>2x EMA) — at real
+  * straggler watchdog: the mean step time between two log steps (host
+    time between their blocking loss reads, over the steps between them);
+    logs an interval slower than 2x the EMA of the earlier ones — at real
     multi-host scale this feeds the coordinator's replace-node decision
+  * host spans (repro/obs.py) around the set-up phases and each step's parts,
+    for the profiler's trace and an installed obs.Recorder
   * elastic restore: checkpoints reload onto a different mesh shape
 
 CLI:  PYTHONPATH=src python -m repro.launch.train --arch llama_60m --steps 200
@@ -27,6 +31,7 @@ from repro.data.pipeline import DataConfig, SyntheticC4
 from repro.distributed.step import make_train_step, params_specs, opt_state_specs
 from repro.launch import mesh as mesh_lib
 from repro.models import model as M
+from repro.obs import span, step_span
 
 
 @dataclasses.dataclass
@@ -454,10 +459,15 @@ def train_loop(run: RunConfig, tc: TrainConfig, cfg=None, on_step=None,
                 static_argnums=(3,))
         return opt, jitted, driver, maybe_refresh, resync
 
-    p_shard, o_shard = state_shardings(cfg, tc, rules)
+    with span("setup.shardings"):
+        p_shard, o_shard = state_shardings(cfg, tc, rules)
     tc_eff = tc
-    opt, jitted, driver, maybe_refresh, resync = build_programs(tc_eff)
-    params, opt_state = build_state(cfg, tc, rules, key)
+    with span("setup.programs"):
+        opt, jitted, driver, maybe_refresh, resync = build_programs(tc_eff)
+    with span("setup.state") as rec:
+        params, opt_state = build_state(cfg, tc, rules, key)
+        if rec is not None:  # a recorder's span holds opt.init's device work
+            jax.block_until_ready((params, opt_state))
     guard = None
     recov = None
     if guarded:
@@ -509,114 +519,132 @@ def train_loop(run: RunConfig, tc: TrainConfig, cfg=None, on_step=None,
         return params, opt_state, guard, start
 
     start_step = 0
-    # guarded runs only trust checkpoints that pass integrity validation —
-    # a torn/corrupted latest degrades to the one before it
-    latest = ckpt.latest_valid_step() if guarded else ckpt.latest_step()
-    if latest is not None:
-        params, opt_state, guard, start_step = try_restore(
-            params, opt_state, guard, driver, latest)
-        print(f"[train] resumed from step {latest}")
+    with span("setup.restore"):
+        # guarded runs only trust checkpoints that pass integrity validation
+        # — a torn/corrupted latest degrades to the one before it
+        latest = ckpt.latest_valid_step() if guarded else ckpt.latest_step()
+        if latest is not None:
+            params, opt_state, guard, start_step = try_restore(
+                params, opt_state, guard, driver, latest)
+            print(f"[train] resumed from step {latest}")
 
     ema_dt = None
+    t_logged = step_logged = None  # host time and step of the last loss read
     metrics = {}
     preempt_flag = os.path.join(run.ckpt_dir, "PREEMPT")
     step = start_step
     while step < run.steps:
-        t0 = time.time()
-        batch = data.batch(step)
-        if maybe_refresh is not None:
-            opt_state = maybe_refresh(params, opt_state, batch, step)
-            if (injector is not None and driver is not None
-                    and driver.pending is not None
-                    and injector.take("corrupt_pending", step)):
-                print(f"[faults] poisoning in-flight pending buffer at step {step}")
-                driver.pending = injector.poison_pending(driver.pending)
-        if guarded:
-            if tc.fault_hooks:
-                from repro.robust import identity_fault
-
-                fault = (injector.traced_fault(step) if injector is not None
-                         else identity_fault())
-                params, opt_state, guard, metrics = jitted(
-                    params, opt_state, guard, batch, fault)
-            else:
-                params, opt_state, guard, metrics = jitted(
-                    params, opt_state, guard, batch)
-            ok = bool(metrics["guard_ok"])
-            if not ok:
-                print(f"[guard] anomalous step {step}: update skipped "
-                      f"(total skips {int(metrics['guard_skips'])})")
-        else:
-            ok = True
-            params, opt_state, metrics = jitted(params, opt_state, batch)
-        if recov is not None and recov.observe_step(ok):
-            n = recov.start_rollback()
-            ckpt.wait()  # let an in-flight save commit before choosing a target
-            if tc.recover_lr_decay < 1.0:
-                tc_eff = dataclasses.replace(
-                    tc_eff, lr=tc_eff.lr * tc.recover_lr_decay)
-                opt, jitted, driver, maybe_refresh, resync = build_programs(tc_eff)
-            elif driver is not None:
-                driver.pending = None  # an in-flight refresh may be the poison
-                driver._prev_batch = None
-            params, opt_state = build_state(cfg, tc_eff, rules, key)
-            from repro.robust import init_guard_state
-
-            # the guard's running stats only absorb ACCEPTED steps, so the
-            # checkpointed monitor is clean by construction — restoring it
-            # keeps the z-score armed across the rollback (a fresh one would
-            # be blind to spikes for another full warmup)
-            guard = init_guard_state()
-            which = ckpt.latest_valid_step()
-            if which is not None:
-                params, opt_state, guard, step = try_restore(
-                    params, opt_state, guard, driver, which)
-            else:
-                step = 0  # nothing valid on disk — restart from init
-            print(f"[recover] rollback {n}/{tc.recover_max_rollbacks}: "
-                  f"restored step {which}, resuming at step {step}"
-                  + (f", lr -> {tc_eff.lr:.2e}" if tc.recover_lr_decay < 1.0 else ""))
-            if resync is not None:
-                opt_state = resync(
-                    params, opt_state, data.batch(step),
-                    0 if tc_eff.galore.refresh_stagger else None)
-                if driver is not None:
-                    driver.prime_stale(data.batch(step))
-            continue  # re-enter the loop at the restored step
-        dt = time.time() - t0
-        ema_dt = dt if ema_dt is None else 0.9 * ema_dt + 0.1 * dt
-        if dt > 2.0 * ema_dt and step > start_step + 3:
-            print(f"[watchdog] straggler step {step}: {dt:.3f}s vs EMA {ema_dt:.3f}s")
-        if step % run.log_every == 0:
-            print(f"[train] step {step} loss {float(metrics['loss']):.4f} ({dt*1e3:.0f} ms)")
-        if on_step is not None:
-            on_step(step, metrics)
-        if run.ckpt_every and step > 0 and step % run.ckpt_every == 0:
-            tree = {"params": params, "opt_state": opt_state}
-            if driver is not None and driver.pending is not None:
-                tree["pending"] = driver.pending  # in-flight refresh rides along
+        with step_span(step):
+            with span("train.data"):
+                batch = data.batch(step)
+            if maybe_refresh is not None:
+                with span("train.refresh"):
+                    opt_state = maybe_refresh(params, opt_state, batch, step)
+                if (injector is not None and driver is not None
+                        and driver.pending is not None
+                        and injector.take("corrupt_pending", step)):
+                    print(f"[faults] poisoning in-flight pending buffer at step {step}")
+                    driver.pending = injector.poison_pending(driver.pending)
             if guarded:
-                tree["guard"] = guard  # monitor stats resume with the run
-            ckpt.save(step, tree, extra_meta={"data": data.state(step)})
-            if injector is not None:
-                if injector.take("corrupt_ckpt", step):
-                    ckpt.wait()  # corrupt the COMMITTED files, not the tmp
-                    print(f"[faults] corrupting latest checkpoint after step {step}")
-                    injector.corrupt_latest(run.ckpt_dir)
-                if injector.take("kill_save", step):
-                    ckpt.wait()
-                    print(f"[faults] simulating kill mid-save at step {step}")
-                    injector.leave_stale_tmp(run.ckpt_dir, step)
-        if os.path.exists(preempt_flag):
-            print(f"[train] preemption signal at step {step}: checkpoint + exit")
-            tree = {"params": params, "opt_state": opt_state}
-            if driver is not None and driver.pending is not None:
-                tree["pending"] = driver.pending
-            if guarded:
-                tree["guard"] = guard
-            ckpt.save(step, tree, block=True)
-            os.remove(preempt_flag)
-            return params, opt_state, metrics, step
+                with span("train.dispatch"):
+                    if tc.fault_hooks:
+                        from repro.robust import identity_fault
+
+                        fault = (injector.traced_fault(step) if injector is not None
+                                 else identity_fault())
+                        params, opt_state, guard, metrics = jitted(
+                            params, opt_state, guard, batch, fault)
+                    else:
+                        params, opt_state, guard, metrics = jitted(
+                            params, opt_state, guard, batch)
+                ok = bool(metrics["guard_ok"])
+                if not ok:
+                    print(f"[guard] anomalous step {step}: update skipped "
+                          f"(total skips {int(metrics['guard_skips'])})")
+            else:
+                ok = True
+                with span("train.dispatch"):
+                    params, opt_state, metrics = jitted(params, opt_state, batch)
+            if recov is not None and recov.observe_step(ok):
+                n = recov.start_rollback()
+                ckpt.wait()  # let an in-flight save commit before choosing a target
+                if tc.recover_lr_decay < 1.0:
+                    tc_eff = dataclasses.replace(
+                        tc_eff, lr=tc_eff.lr * tc.recover_lr_decay)
+                    opt, jitted, driver, maybe_refresh, resync = build_programs(tc_eff)
+                elif driver is not None:
+                    driver.pending = None  # an in-flight refresh may be the poison
+                    driver._prev_batch = None
+                params, opt_state = build_state(cfg, tc_eff, rules, key)
+                from repro.robust import init_guard_state
+
+                # the guard's running stats only absorb ACCEPTED steps, so the
+                # checkpointed monitor is clean by construction — restoring it
+                # keeps the z-score armed across the rollback (a fresh one
+                # would be blind to spikes for another full warmup)
+                guard = init_guard_state()
+                which = ckpt.latest_valid_step()
+                if which is not None:
+                    params, opt_state, guard, step = try_restore(
+                        params, opt_state, guard, driver, which)
+                else:
+                    step = 0  # nothing valid on disk — restart from init
+                print(f"[recover] rollback {n}/{tc.recover_max_rollbacks}: "
+                      f"restored step {which}, resuming at step {step}"
+                      + (f", lr -> {tc_eff.lr:.2e}" if tc.recover_lr_decay < 1.0 else ""))
+                if resync is not None:
+                    opt_state = resync(
+                        params, opt_state, data.batch(step),
+                        0 if tc_eff.galore.refresh_stagger else None)
+                    if driver is not None:
+                        driver.prime_stale(data.batch(step))
+                continue  # re-enter the loop at the restored step
+            if step % run.log_every == 0:
+                with span("train.log"):
+                    loss = float(metrics["loss"])  # the loop's one wait on the device
+                now = time.perf_counter()
+                timing = ""
+                if step_logged is not None and step > step_logged:
+                    # dispatch returns before the device is done: a step's
+                    # time shows only between two waits
+                    dt = (now - t_logged) / (step - step_logged)
+                    if ema_dt is not None and dt > 2.0 * ema_dt:
+                        print(f"[watchdog] straggler steps {step_logged + 1}-{step}: "
+                              f"{dt:.3f}s/step vs EMA {ema_dt:.3f}s")
+                    ema_dt = dt if ema_dt is None else 0.9 * ema_dt + 0.1 * dt
+                    timing = f" ({dt * 1e3:.0f} ms/step)"
+                t_logged, step_logged = now, step
+                print(f"[train] step {step} loss {loss:.4f}{timing}")
+            if on_step is not None:
+                with span("train.hook"):
+                    on_step(step, metrics)
+            with span("train.ckpt"):
+                if run.ckpt_every and step > 0 and step % run.ckpt_every == 0:
+                    tree = {"params": params, "opt_state": opt_state}
+                    if driver is not None and driver.pending is not None:
+                        tree["pending"] = driver.pending  # in-flight refresh rides along
+                    if guarded:
+                        tree["guard"] = guard  # monitor stats resume with the run
+                    ckpt.save(step, tree, extra_meta={"data": data.state(step)})
+                    if injector is not None:
+                        if injector.take("corrupt_ckpt", step):
+                            ckpt.wait()  # corrupt the COMMITTED files, not the tmp
+                            print(f"[faults] corrupting latest checkpoint after step {step}")
+                            injector.corrupt_latest(run.ckpt_dir)
+                        if injector.take("kill_save", step):
+                            ckpt.wait()
+                            print(f"[faults] simulating kill mid-save at step {step}")
+                            injector.leave_stale_tmp(run.ckpt_dir, step)
+                if os.path.exists(preempt_flag):
+                    print(f"[train] preemption signal at step {step}: checkpoint + exit")
+                    tree = {"params": params, "opt_state": opt_state}
+                    if driver is not None and driver.pending is not None:
+                        tree["pending"] = driver.pending
+                    if guarded:
+                        tree["guard"] = guard
+                    ckpt.save(step, tree, block=True)
+                    os.remove(preempt_flag)
+                    return params, opt_state, metrics, step
         step += 1
     if driver is not None:
         opt_state = driver.flush(opt_state)

@@ -28,6 +28,12 @@ Three layouts, one module:
 All quantize paths compute in f32 and are shape-polymorphic over leading
 batch dims. Non-divisible tails are zero-padded before the absmax, which is
 exactly what the in-kernel masking reproduces (see galore_fused.py).
+
+The INT8 codecs run under the named scopes ``int8_quantize`` and
+``int8_dequantize``, so every op they emit carries the scope in its HLO
+``op_name`` under any caller's jit, grad or vmap: a profiler trace can tell
+their device time from the rest of the optimizer's. Scopes are metadata and
+change no instruction; the Pallas kernels' own epilogues are not in them.
 """
 from __future__ import annotations
 
@@ -44,6 +50,17 @@ QBLOCK = 128  # axis-blocked codec block (TPU lane width)
 # and V so the two moments of one element never share a coin flip)
 SR_SALT_M = 0x5BD1E995
 SR_SALT_V = 0xC2B2AE35
+
+
+def _scoped(name: str):
+    """Run the decorated function under ``jax.named_scope(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -110,6 +127,7 @@ def _pad_to_blocks(x: jnp.ndarray, block: int = BLOCK) -> tuple[jnp.ndarray, int
     return flat.reshape(-1, block), pad
 
 
+@_scoped("int8_quantize")
 def quantize(x: jnp.ndarray, signed: bool = True):
     """x (any shape) -> (codes uint8 (nblocks, BLOCK), absmax (nblocks,) f32)."""
     book = jnp.asarray(dynamic_codebook(signed))
@@ -121,6 +139,7 @@ def quantize(x: jnp.ndarray, signed: bool = True):
     return codes, absmax
 
 
+@_scoped("int8_dequantize")
 def dequantize(codes: jnp.ndarray, absmax: jnp.ndarray, shape, signed: bool = True):
     book = jnp.asarray(dynamic_codebook(signed))
     vals = book[codes.astype(jnp.int32)] * absmax[:, None]
@@ -225,6 +244,7 @@ def _stochastic_codes(normed: jnp.ndarray, book: jnp.ndarray,
     return (lo + (u < frac).astype(jnp.int32)).astype(jnp.uint8)
 
 
+@_scoped("int8_quantize")
 def quantize_axis(x: jnp.ndarray, *, axis: int = -1, block: int = QBLOCK,
                   signed: bool = True, stochastic: bool = False,
                   count=None, salt: int = 0):
@@ -259,6 +279,7 @@ def quantize_axis(x: jnp.ndarray, *, axis: int = -1, block: int = QBLOCK,
     return codes, absmax
 
 
+@_scoped("int8_dequantize")
 def dequantize_axis(codes: jnp.ndarray, scales: jnp.ndarray, *, axis: int = -1,
                     block: int = QBLOCK, signed: bool = True) -> jnp.ndarray:
     axis = axis % codes.ndim
