@@ -211,15 +211,22 @@ def train_phase(dev):
         params, opt_state, _, _ = T.train_loop(run, tc, on_step=on_step,
                                                on_build=built.append)
     wall = time.perf_counter() - t0
-    # the kernel path of every GaLore leaf, as the traced step dispatched it
+    # the kernel path of every GaLore leaf and of every full-shape int8
+    # leaf, as the traced step dispatched it
     leaves = galore_leaves(cfg, tc)
-    step_routes = routes[:len(leaves)]
+    galore_routes = [r for r in routes if r[0] != "full"]
+    step_routes = galore_routes[:len(leaves)]
     for (path, shape), (side, g_shape, route) in zip(leaves, step_routes):
         check(shape == g_shape, f"update order: {path} {shape} vs {g_shape}")
         print(f"[smoke] kernel path {path} {shape} {side}: {route}")
-    check(len(step_routes) == len(leaves) and routes
-          and all(r == "fused" for *_, r in routes),
+    check(len(step_routes) == len(leaves) and galore_routes
+          and all(r == "fused" for *_, r in galore_routes),
           "a GaLore leaf does not run the fused Pallas kernel")
+    full_routes = sorted({r for r in routes if r[0] == "full"})
+    for _, g_shape, route in full_routes:
+        print(f"[smoke] full-shape int8 leaf {g_shape}: {route}")
+    check(all(r == "fused" for *_, r in full_routes),
+          "a full-shape int8 leaf does not run the epilogue's fold")
     steps = sorted(losses)
     check(steps == list(range(STEPS)), f"steps run: {steps}")
     for s in steps:
@@ -338,7 +345,9 @@ def four_chip_phase():
               "kernel paths " + ", ".join(f"{side} {shape}: {route}"
                                           for side, shape, route
                                           in sorted(set(routes))))
-        check(routes and all(r == "fused" for *_, r in routes),
+        # full-shape int8 leaves keep the XLA codec on a multi-device mesh
+        galore_routes = [r for r in routes if r[0] != "full"]
+        check(galore_routes and all(r == "fused" for *_, r in galore_routes),
               "a GaLore leaf does not run the fused Pallas kernel")
         return ([losses[s] for s in sorted(losses)], params, opt_state,
                 (mesh, tc, built[-1]))

@@ -30,7 +30,8 @@ quantizes moments, galore manages the Adam math itself (the inner transform
 is bypassed, so b1/b2/eps are required exactly as for fused_adam) and int8
 leaves store {"q": codes, "scale": absmax} dicts in place of the fp32 m/v
 arrays — in the axis-blocked layout the fused kernels consume, so the
-dequant→Adam→requant epilogue runs in one VMEM pass on TPU. Quantized
+dequant→Adam→requant epilogue runs in one VMEM pass on TPU (with
+`fused_adam`, full-shape int8 leaves such as embeddings too). Quantized
 projectors (bf16 / packed int4) are dequantized on read in every path. The
 all-fp32 default leaves both layout and numerics bit-identical.
 
@@ -311,7 +312,9 @@ def _managed_adam_update(grads, proj_eff, inner_state, plans, cfg: GaLoreConfig,
     updated in place) when `fused`, else the composable project → Adam →
     back-project composition; int8-moment leaves (plan.moments) run the
     dequant→Adam→requant epilogue in either mode. Other leaves get the same
-    Adam math at full shape. Reads and writes the scale_by_adam state layout
+    Adam math at full shape: with `fused`, the int8 ones through
+    `ops.adam8_full_step` (the epilogue's fold where their shape admits
+    it, else the XLA codec). Reads and writes the scale_by_adam state layout
     {m, v, count} (int8 leaves store {"q", "scale"} dicts). Per-leaf ranks
     are carried by the array shapes — each distinct (side, m, r, n) gets its
     own kernel specialization, which is exactly what Pallas wants.
@@ -350,15 +353,23 @@ def _managed_adam_update(grads, proj_eff, inner_state, plans, cfg: GaLoreConfig,
     def leaf(g, P, m_st, v_st, plan, p):
         qm = plan.moments == "int8"
         if not plan.galore:
-            # same bias-corrected Adam math as the kernel, from the single
-            # source of truth (also what scale_by_adam computes)
-            if qm:
-                m, v = dequant_mv(m_st, v_st, plan)
+            if fused and qm:
+                # the epilogue's fold where the leaf's shape admits it
+                out, mq, ms, vq, vs = ops.adam8_full_step(
+                    g, m_st["q"], m_st["scale"], v_st["q"], v_st["scale"],
+                    count, b1=b1, b2=b2, eps=eps, stochastic=stochastic)
+                m_t, v_t = {"q": mq, "scale": ms}, {"q": vq, "scale": vs}
             else:
-                m, v = m_st, v_st
-            out, m_t, v_t = ref.lowrank_adam_update(g, m, v, count, b1, b2, eps)
-            if qm:
-                m_t, v_t = requant_mv(m_t, v_t, plan)
+                # same bias-corrected Adam math as the kernel, from the
+                # single source of truth (also what scale_by_adam computes)
+                if qm:
+                    m, v = dequant_mv(m_st, v_st, plan)
+                else:
+                    m, v = m_st, v_st
+                out, m_t, v_t = ref.lowrank_adam_update(g, m, v, count, b1,
+                                                        b2, eps)
+                if qm:
+                    m_t, v_t = requant_mv(m_t, v_t, plan)
             m_t = constrain_zero_moment(m_t, plan)
             v_t = constrain_zero_moment(v_t, plan)
             return finish(out.astype(g.dtype), p), m_t, v_t

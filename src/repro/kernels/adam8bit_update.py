@@ -1,24 +1,20 @@
-"""Fused 8-bit Adam update: thin shim over the parametric epilogue builder.
+"""Fused 8-bit Adam update on flat (nb, 256) blocks: a shim over the
+epilogue's fold.
 
-Historically this module carried its own Pallas kernel (dequant → Adam →
-requant over flat (tile_blocks × 256)-element tiles). That body was the
-same math as the quantized GaLore epilogue in galore_fused.py with the
-projection sandwich deleted, so it is now expressed as exactly that:
-`galore_fused.adam8bit_blocks_update` runs the epilogue with
-``project=False`` (R = G), one quantization block per tile row
-(qblock = BLOCK = the swept extent) and the flat block axis folded into the
-batch grid. One kernel body serves every quantized variant; this shim keeps
-the historical signature (including the codebook args — the epilogue owns
-its codebooks, which are the same `dynamic_codebook` tables every caller
-ever passed) and the historical shapes, bitwise.
-
-Quantization inside the kernel uses a branch-free nearest-codebook search:
-idx = Σ (x ≥ midpoint_i) over the 255 midpoints — a (tile, 256, 255) compare
-that maps onto the VPU; no sort/searchsorted primitive needed on TPU.
+Flat blocks of 256 are the axis layout with n = qblock = BLOCK: one
+quantization block per row. `galore_fused.adam8_axis_update` runs them
+through the quantized GaLore epilogue with ``project=False`` (R = G),
+TILE_BLOCKS rows per grid step, so one kernel body serves every quantized
+variant. This shim keeps the historical signature (including the codebook
+args — the epilogue owns its codebooks, which are the same
+`dynamic_codebook` tables every caller ever passed) and the historical
+shapes, bitwise.
 """
 from __future__ import annotations
 
-from repro.kernels.galore_fused import adam8bit_blocks_update
+import jax.numpy as jnp
+
+from repro.kernels.galore_fused import adam8_axis_update
 from repro.optim.quant8 import BLOCK
 
 TILE_BLOCKS = 16  # rows of 256 elements per grid step
@@ -37,8 +33,9 @@ def adam8bit_update(
     codebooks, which are what every caller passes). Returns
     (update, m_codes', m_scale', v_codes', v_scale')."""
     del book_signed, book_unsigned
-    return adam8bit_blocks_update(
-        g_blocks, m_codes, m_scale, v_codes, v_scale, count,
-        b1=b1, b2=b2, eps=eps, block=BLOCK, tile_blocks=TILE_BLOCKS,
-        interpret=interpret,
-    )
+    # moments are not aliased: the eager adam8bit_step callers reuse them
+    upd, mq, ms, vq, vs = adam8_axis_update(
+        g_blocks.astype(jnp.float32), m_codes, m_scale[:, None], v_codes,
+        v_scale[:, None], count, b1=b1, b2=b2, eps=eps, qblock=BLOCK,
+        rows_per_step=TILE_BLOCKS, alias_moments=False, interpret=interpret)
+    return upd, mq, ms[:, 0], vq, vs[:, 0]

@@ -81,6 +81,10 @@ Variants
     place — the full-size f32 update write disappears from the step
     entirely (the launcher's lr/weight-decay chain is folded in via
     eta = -lr). The two-step emit path remains the numerics oracle.
+
+  * fold (`adam8_axis_update`, project=False): no projector, R = G — the
+    int8 Adam update of a full-shape leaf (embeddings, leaves GaLore does
+    not project), its rows folded into the batch grid axis.
 """
 from __future__ import annotations
 
@@ -111,6 +115,11 @@ DEFAULT_BN = 512
 # leaves the compiler room for what `_vmem_bytes` does not model.
 VMEM_LIMIT = 96 * 1024 * 1024
 VMEM_BUDGET = 64 * 1024 * 1024
+# the full-shape int8 fold (`adam8_axis_update`): its widest lane tile, and
+# the leaf elements one grid step aims to cover (1 MiB of a bf16 gradient),
+# so that the per-step overhead stays small against the tile's work
+FOLD_MAX_TILE = 4096
+FOLD_STEP_ELEMS = 1 << 19
 _LANES = 128
 # f32 matmuls in full f32 (multi-pass on the MXU): the TPU's default rounds
 # f32 operands to bf16, and Adam's normalization N̂ = m / √v turns that
@@ -225,7 +234,7 @@ def _int4_decode(q):
     return out
 
 
-def _epilogue_kernel(*refs, side, quant, quant_p, project, apply_w, w_dtype,
+def _epilogue_kernel(*refs, side, quant, quant_p, project, apply_w, out_dtype,
                      b1, b2, eps, alpha, wd, long_dim, tile, qblock, p_short,
                      stochastic):
     """One body for every fused kernel variant (side × int8 moments ×
@@ -253,8 +262,8 @@ def _epilogue_kernel(*refs, side, quant, quant_p, project, apply_w, w_dtype,
     codec.quantize4_axis — row i shares a byte with row i + m_pad/2) plus
     per-(QBLOCK-block, column) absmax scales; the first grid step of each
     batch element unpacks them into the f32 scratch, which the sweep reuses.
-    project=False: no P at all, R = G — the flat-block 8-bit Adam update
-    (adam8bit_update.py) with the moment shape equal to the gradient shape.
+    project=False: no P at all, R = G — full-shape 8-bit Adam
+    (`adam8_axis_update`) with the moment shape equal to the gradient shape.
     stochastic: Q-GaLore stochastic rounding on the requant, keyed by a
     counter hash of (logical ravel index, step count, per-moment salt) that
     is bit-shared with codec.quantize_axis(stochastic=True).
@@ -325,13 +334,13 @@ def _epilogue_kernel(*refs, side, quant, quant_p, project, apply_w, w_dtype,
                 preferred_element_type=jnp.float32, precision=_F32,
             )
     else:
-        R = g  # flat-block Adam: the "compact" moment IS the gradient shape
+        R = g  # full-shape Adam: the "compact" moment IS the gradient shape
 
     c1, c2 = coef_ref[0], coef_ref[1]
 
     def adam(m_old, v_old, r_):
         m_new = b1 * m_old + (1.0 - b1) * r_
-        v_new = b2 * v_old + (1.0 - b2) * r_ * r_
+        v_new = b2 * v_old + (1.0 - b2) * (r_ * r_)  # as ref.py rounds it
         return m_new, v_new
 
     def normalize(m_new, v_new):
@@ -446,27 +455,29 @@ def _epilogue_kernel(*refs, side, quant, quant_p, project, apply_w, w_dtype,
         )
     if apply_w:
         w = w_ref[0].astype(jnp.float32)
-        out_ref[0] = (w + coef_ref[2] * (gt + wd * w)).astype(w_dtype)
-    else:
-        out_ref[0] = gt
+        gt = w + coef_ref[2] * (gt + wd * w)
+    out_ref[0] = gt.astype(out_dtype)
 
 
 def _fused_epilogue_call(side, quant, apply_w, P, G, W, moments, count, *,
                          b1, b2, eps, alpha, eta, wd, tile0, interpret,
                          quant_p=False, project=True, alias_moments=True,
-                         qblock=QBLOCK, stochastic=False):
+                         qblock=QBLOCK, stochastic=False, out_dtype=None):
     """Build + launch one fused-kernel variant. `moments` is (Mq, Ms, Vq, Vs)
     when quant else (M, V); returns (out, *new_moments).
 
     quant_p: P is a codec.quantize4_axis qstate dict — packed codes +
     per-block scales go to the kernel whole-resident and dequantize in VMEM.
-    project=False (P is None): no projection sandwich, R = G — the flat
-    8-bit Adam update as a degenerate epilogue (mom shape == G shape); the
-    moments are NOT aliased in that mode (alias_moments=False) because the
-    eager adam8bit callers reuse their inputs.
-    qblock: the moment quantization block (QBLOCK for the GaLore layouts,
-    optim.quant8.BLOCK for the flat fold).
+    project=False (P is None): no projection sandwich, R = G — full-shape
+    8-bit Adam as a degenerate epilogue (mom shape == G shape; see
+    `adam8_axis_update`).
+    alias_moments: update the moments in place (False for eager callers
+    that reuse their inputs).
+    qblock: the moment quantization block (QBLOCK for the axis layouts,
+    optim.quant8.BLOCK for the flat blocks).
     stochastic: stochastic-rounding requant (quant only).
+    out_dtype: dtype of the emitted update (f32 by default, W's when
+    applying).
 
     Int8 scales travel in a tile-major 4-D layout, (L, tiles, r, nbt) on the
     left and (L, tiles, nbt, r) on the right, so each grid step's scale
@@ -600,7 +611,10 @@ def _fused_epilogue_call(side, quant, apply_w, P, G, W, moments, count, *,
         operands += [_table(book_s), _table(book_u),
                      _table(mids(book_s)), _table(mids(book_u))]
 
-    out_dtype = W.dtype if apply_w else jnp.float32
+    if apply_w:
+        out_dtype = W.dtype
+    elif out_dtype is None:
+        out_dtype = jnp.float32
     out_shapes = [jax.ShapeDtypeStruct((L, m, n), out_dtype)]
     out_specs = [g_spec]
     if quant:
@@ -612,8 +626,7 @@ def _fused_epilogue_call(side, quant, apply_w, P, G, W, moments, count, *,
         out_shapes += [jax.ShapeDtypeStruct((L,) + mom_shape, jnp.float32)] * 2
         out_specs += [mom_spec, mom_spec]
 
-    # moments (and W, when applying) are donated and updated in place;
-    # the fold path skips aliasing because its eager callers reuse inputs
+    # moments (and W, when applying) are donated and updated in place
     aliases = {}
     if alias_moments:
         mom_in_base = n_p + (2 if apply_w else 1)
@@ -623,7 +636,7 @@ def _fused_epilogue_call(side, quant, apply_w, P, G, W, moments, count, *,
 
     kernel = functools.partial(
         _epilogue_kernel, side=side, quant=quant, quant_p=quant_p,
-        project=project, apply_w=apply_w, w_dtype=out_dtype, b1=b1, b2=b2,
+        project=project, apply_w=apply_w, out_dtype=out_dtype, b1=b1, b2=b2,
         eps=eps, alpha=alpha, wd=wd, long_dim=long_dim, tile=tile,
         qblock=qblock, p_short=short, stochastic=stochastic,
     )
@@ -751,43 +764,59 @@ def galore_fused_adam8_apply_step_right(P, G, W, Mq, Ms, Vq, Vs, count, *,
         quant_p=is_qstate(P), stochastic=stochastic, interpret=interpret)
 
 
-def adam8bit_blocks_update(g_blocks, m_codes, m_scale, v_codes, v_scale,
-                           count, *, b1=0.9, b2=0.999, eps=1e-8,
-                           block: int = 256, tile_blocks: int = 16,
-                           interpret: bool = False):
-    """Flat-block 8-bit Adam as a degenerate epilogue (project=False).
+def adam8_axis_update(G, Mq, Ms, Vq, Vs, count, *, b1=0.9, b2=0.999,
+                      eps=1e-8, stochastic: bool = False, qblock: int = QBLOCK,
+                      rows_per_step: int | None = None,
+                      alias_moments: bool = True, interpret: bool = False):
+    """Full-shape 8-bit Adam (no projection) as the epilogue's fold.
 
-    g_blocks (nb, block) f32, codes (nb, block) uint8, scales (nb,) f32.
-    The nb axis is padded to a multiple of `tile_blocks` and folded into the
-    batch grid axis as (L, tb, block) "left" tiles with r == tb and one
-    quantization block per row (qblock == block == the swept extent), so
-    the dequant→Adam→requant math runs through the exact same traced ops as
-    the GaLore epilogues. Zero padding is inert: a zero block dequantizes to
-    zero (scale pad is 0), updates to zero, and requantizes to code 128 /
-    scale 1e-12, and padded rows are sliced off before returning. Moments
-    are NOT aliased (the eager adam8bit_step caller reuses its inputs).
-    Returns (update (nb, block) f32, m_codes', m_scale', v_codes', v_scale').
-    """
-    nb, blk = g_blocks.shape
-    assert blk == block, (g_blocks.shape, block)
-    tb = min(tile_blocks, nb)
-    L = -(-nb // tb)
-    pad = L * tb - nb
+    G (..., rows, n); Mq/Vq uint8 codes of G's shape and Ms/Vs f32 scales
+    (..., rows, ceil(n / qblock)): the layout of
+    codec.quantize_axis(axis=-1). The leading dims and the rows fold into
+    the batch grid axis as (L, tb, n) "left" tiles with r == tb, and the
+    kernel sweeps n in lane tiles of whole quantization blocks: one tile
+    where n <= FOLD_MAX_TILE (the scales then need no relayout), else
+    FOLD_MAX_TILE with the last tile masked. The stochastic coin's ravel
+    index (lb·tb + row)·n + col is the leaf's own, so stochastic codes
+    equal codec.quantize_axis(stochastic=True)'s bit for bit.
 
-    def fold(x, fill=0):
+    tb (`rows_per_step`) sets the work per grid step: by default the
+    largest power of two, at least 32, whose tile holds at most
+    FOLD_STEP_ELEMS elements, halved while it does not divide the rows,
+    down to 32 (the chip loads uint8 codes in whole 8-row tiles, so a
+    7-row stack of norm scales takes a 32-row step). Rows that tb does not
+    divide are padded with zeros and sliced off: a zero row dequantizes to
+    0, stays 0 and emits 0. The moments are updated in place unless
+    `alias_moments` is False.
+    Returns (update in G's dtype, Mq', Ms', Vq', Vs')."""
+    shape = G.shape
+    n = shape[-1]
+    rows = int(np.prod(shape[:-1]))
+    tile = min(n, FOLD_MAX_TILE)
+    if rows_per_step is None:
+        tb = 1 << (max(FOLD_STEP_ELEMS // tile, 32).bit_length() - 1)
+        while rows % tb and tb > 32:
+            tb //= 2
+    else:
+        tb = min(rows_per_step, rows)
+    L = -(-rows // tb)
+    pad = L * tb - rows
+
+    def fold(x):
+        x = x.reshape(rows, x.shape[-1])
         if pad:
-            widths = ((0, pad),) + ((0, 0),) * (x.ndim - 1)
-            x = jnp.pad(x, widths, constant_values=fill)
-        return x.reshape((L, tb) + x.shape[1:])
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+        return x.reshape(L, tb, x.shape[-1])
 
-    g = fold(g_blocks.astype(jnp.float32))
-    moments = (fold(m_codes), fold(m_scale)[..., None],
-               fold(v_codes), fold(v_scale)[..., None])
     outs = _fused_epilogue_call(
-        "left", True, False, None, g, None, moments, count,
-        b1=b1, b2=b2, eps=eps, alpha=1.0, eta=0.0, wd=0.0, tile0=block,
-        project=False, alias_moments=False, qblock=block,
-        interpret=interpret)
-    unfold = lambda x: x.reshape((L * tb,) + x.shape[2:])[:nb]
-    upd, mq, ms, vq, vs = (unfold(o) for o in outs)
-    return upd, mq, ms[..., 0], vq, vs[..., 0]
+        "left", True, False, None, fold(G), None,
+        tuple(fold(x) for x in (Mq, Ms, Vq, Vs)), count,
+        b1=b1, b2=b2, eps=eps, alpha=1.0, eta=0.0, wd=0.0, tile0=tile,
+        project=False, alias_moments=alias_moments, qblock=qblock,
+        stochastic=stochastic, out_dtype=G.dtype, interpret=interpret)
+
+    def unfold(x, like):
+        x = x.reshape(L * tb, x.shape[-1])
+        return (x[:rows] if pad else x).reshape(like.shape)
+
+    return tuple(unfold(o, x) for o, x in zip(outs, (G, Mq, Ms, Vq, Vs)))

@@ -59,8 +59,10 @@ _routes: list | None = None
 @contextlib.contextmanager
 def record_routes():
     """Collect `(side, G shape, route)` for every fused GaLore leaf update
-    traced inside the block, in trace order (`route` as `fused_path`
-    names it) — what the traced program really dispatched."""
+    (`route` as `fused_path` names it) and `("full", G shape, route)` for
+    every full-shape int8 Adam leaf (`adam8_full_step`: "fused" or
+    "reference") traced inside the block, in trace order — what the
+    traced program really dispatched."""
     global _routes
     prev, _routes = _routes, []
     try:
@@ -326,6 +328,33 @@ def galore_fused_adam8_apply_step_right(P, G, W, Mq, Ms, Vq, Vs, count, *,
         count, quant=True, zero=False, use_pallas=use_pallas,
         interpret=interpret, b1=b1, b2=b2, eps=eps, alpha=alpha, eta=eta,
         wd=wd, stochastic=stochastic)
+
+
+def adam8_full_step(G, Mq, Ms, Vq, Vs, count, *, b1=0.9, b2=0.999, eps=1e-8,
+                    stochastic=False, use_pallas=None, interpret=False):
+    """Int8 Adam step of a full-shape leaf (one GaLore does not project):
+    dequant M/V → Adam → requant, codes of G's shape and scales blocked by
+    QBLOCK along the last axis (codec.quantize_axis(axis=-1)). Returns
+    (update in G's dtype, Mq', Ms', Vq', Vs').
+
+    Runs the epilogue's fold (`galore_fused.adam8_axis_update`, one
+    kernel, moments updated in place) where the Pallas backend is selected,
+    G is at least 2-D with a last dim of whole 128-lane rows (the
+    in-kernel codebook gathers stay inside one row on the chip), and no
+    multi-device mesh is active; else kernels/ref.py's XLA codec, the
+    implementation of record. Recorded under `record_routes`."""
+    rules = active_rules()
+    fused = bool(_resolve(use_pallas) and G.ndim >= 2
+                 and G.shape[-1] % _LANES == 0
+                 and (rules is None or rules.mesh.size == 1))
+    if _routes is not None:
+        _routes.append(("full", tuple(G.shape),
+                        "fused" if fused else "reference"))
+    kw = dict(b1=b1, b2=b2, eps=eps, stochastic=stochastic)
+    if fused:
+        return galore_fused_k.adam8_axis_update(G, Mq, Ms, Vq, Vs, count,
+                                                interpret=interpret, **kw)
+    return ref.adam8_full_step(G, Mq, Ms, Vq, Vs, count, **kw)
 
 
 def adam8bit_step(g_blocks, m_codes, m_scale, v_codes, v_scale, count,

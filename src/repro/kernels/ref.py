@@ -72,6 +72,34 @@ def galore_fused_adam_step_right(P, G, M, V, count, b1=0.9, b2=0.999, eps=1e-8,
     return galore_project_back_right(P, N_t, alpha), M_t, V_t
 
 
+def adam8_axis_update(R, Mq, Ms, Vq, Vs, count, b1=0.9, b2=0.999, eps=1e-8,
+                      stochastic=False, axis=-1):
+    """Dequant → Adam → requant of axis-blocked INT8 moments (quant/codec.py:
+    blocks of QBLOCK along `axis`), the moment shape being R's. Returns
+    (N_t f32, Mq', Ms', Vq', Vs'). With `stochastic` the requant uses the
+    counter-hash stochastic rounding the kernels share bitwise."""
+    from repro.quant import codec
+
+    m = codec.dequantize_axis(Mq, Ms, axis=axis, signed=True)
+    v = codec.dequantize_axis(Vq, Vs, axis=axis, signed=False)
+    N_t, M_t, V_t = lowrank_adam_update(R, m, v, count, b1, b2, eps)
+    mq, ms = codec.quantize_axis(M_t, axis=axis, signed=True,
+                                 stochastic=stochastic, count=count,
+                                 salt=codec.SR_SALT_M)
+    vq, vs = codec.quantize_axis(V_t, axis=axis, signed=False,
+                                 stochastic=stochastic, count=count,
+                                 salt=codec.SR_SALT_V)
+    return N_t, mq, ms, vq, vs
+
+
+def adam8_full_step(G, Mq, Ms, Vq, Vs, count, b1=0.9, b2=0.999, eps=1e-8,
+                    stochastic=False):
+    """Oracle for the full-shape INT8 fold (no projection, blocks along the
+    last axis). Returns (update in G's dtype, Mq', Ms', Vq', Vs')."""
+    out = adam8_axis_update(G, Mq, Ms, Vq, Vs, count, b1, b2, eps, stochastic)
+    return (out[0].astype(G.dtype),) + out[1:]
+
+
 def galore_fused_adam8_step(P, G, Mq, Ms, Vq, Vs, count, b1=0.9, b2=0.999,
                             eps=1e-8, alpha=1.0, stochastic=False):
     """Oracle for the INT8-moment fused epilogue (left side).
@@ -83,40 +111,19 @@ def galore_fused_adam8_step(P, G, Mq, Ms, Vq, Vs, count, b1=0.9, b2=0.999,
     midpoint-count rule differ only on exact midpoint hits). With
     `stochastic` the requant uses the counter-hash stochastic rounding the
     kernel shares bitwise (codec.quantize_axis(stochastic=True))."""
-    from repro.quant import codec
-
-    R = galore_project(P, G)
-    m = codec.dequantize_axis(Mq, Ms, axis=-1, signed=True)
-    v = codec.dequantize_axis(Vq, Vs, axis=-1, signed=False)
-    N_t, M_t, V_t = lowrank_adam_update(R, m, v, count, b1, b2, eps)
-    out = galore_project_back(P, N_t, alpha)
-    mq, ms = codec.quantize_axis(M_t, axis=-1, signed=True,
-                                 stochastic=stochastic, count=count,
-                                 salt=codec.SR_SALT_M)
-    vq, vs = codec.quantize_axis(V_t, axis=-1, signed=False,
-                                 stochastic=stochastic, count=count,
-                                 salt=codec.SR_SALT_V)
-    return out, mq, ms, vq, vs
+    N_t, *moments = adam8_axis_update(galore_project(P, G), Mq, Ms, Vq, Vs,
+                                      count, b1, b2, eps, stochastic)
+    return (galore_project_back(P, N_t, alpha), *moments)
 
 
 def galore_fused_adam8_step_right(P, G, Mq, Ms, Vq, Vs, count, b1=0.9,
                                   b2=0.999, eps=1e-8, alpha=1.0,
                                   stochastic=False):
     """Right-side INT8-moment oracle: blocks run along the swept m axis."""
-    from repro.quant import codec
-
-    R = galore_project_right(P, G)
-    m = codec.dequantize_axis(Mq, Ms, axis=-2, signed=True)
-    v = codec.dequantize_axis(Vq, Vs, axis=-2, signed=False)
-    N_t, M_t, V_t = lowrank_adam_update(R, m, v, count, b1, b2, eps)
-    out = galore_project_back_right(P, N_t, alpha)
-    mq, ms = codec.quantize_axis(M_t, axis=-2, signed=True,
-                                 stochastic=stochastic, count=count,
-                                 salt=codec.SR_SALT_M)
-    vq, vs = codec.quantize_axis(V_t, axis=-2, signed=False,
-                                 stochastic=stochastic, count=count,
-                                 salt=codec.SR_SALT_V)
-    return out, mq, ms, vq, vs
+    N_t, *moments = adam8_axis_update(galore_project_right(P, G), Mq, Ms, Vq,
+                                      Vs, count, b1, b2, eps, stochastic,
+                                      axis=-2)
+    return (galore_project_back_right(P, N_t, alpha), *moments)
 
 
 def _apply_weight(W, gt, eta, wd):

@@ -344,6 +344,50 @@ def test_stochastic_requant_kernel_matches_oracle(right):
     assert not np.array_equal(np.asarray(det[1]), np.asarray(got[1]))
 
 
+@pytest.mark.parametrize("shape,small_tiles,stochastic", [
+    ((64, 256), False, False),        # a 2-D leaf, one tile
+    ((3, 32, 128), False, False),     # stacked: leading dims fold into rows
+    ((100, 128), True, False),        # 100 rows, 32 per grid step: padded
+    ((7, 640), True, False),          # 3 lane tiles of 256, the last ragged;
+                                      # 7 rows, as a stack of norm scales
+    ((3, 40, 384), True, True),       # all of the above, stochastic codes
+], ids=["2d", "stacked", "ragged_rows", "ragged_tiles", "stochastic"])
+def test_full_shape_int8_fold_matches_codec(monkeypatch, shape, small_tiles,
+                                            stochastic):
+    """The epilogue's fold over a full-shape int8 leaf (no projection) vs
+    dequantize → Adam → quantize_axis. `small_tiles` shrinks the fold's
+    lane tile and grid step, so that small leaves span several tiles and
+    row counts the step does not divide; the stochastic coin's index is
+    the leaf's own ravel index, so its codes agree bit for bit."""
+    from repro.kernels import galore_fused as F
+
+    if small_tiles:
+        monkeypatch.setattr(F, "FOLD_MAX_TILE", 256)
+        monkeypatch.setattr(F, "FOLD_STEP_ELEMS", 4096)
+    ks = jax.random.split(jax.random.PRNGKey(35), 3)
+    G = jax.random.normal(ks[0], shape).astype(jnp.bfloat16)
+    mq, ms = codec.quantize_axis(jax.random.normal(ks[1], shape) * 0.01,
+                                 axis=-1, signed=True)
+    vq, vs = codec.quantize_axis(
+        jnp.abs(jax.random.normal(ks[2], shape)) * 1e-4, axis=-1, signed=False)
+    count = jnp.int32(6)
+    got = ops.adam8_full_step(G, mq, ms, vq, vs, count, stochastic=stochastic,
+                              use_pallas=True, interpret=True)
+    m = codec.dequantize_axis(mq, ms, axis=-1, signed=True)
+    v = codec.dequantize_axis(vq, vs, axis=-1, signed=False)
+    upd, m_t, v_t = ref.lowrank_adam_update(G, m, v, count)
+    kw = dict(axis=-1, stochastic=stochastic, count=count)
+    want = (upd.astype(G.dtype),
+            *codec.quantize_axis(m_t, signed=True, salt=codec.SR_SALT_M, **kw),
+            *codec.quantize_axis(v_t, signed=False, salt=codec.SR_SALT_V, **kw))
+    assert got[0].dtype == G.dtype
+    _check([x.astype(jnp.float32) for x in got[:1]] + list(got[1:]),
+           [want[0].astype(jnp.float32)] + list(want[1:]), shape)
+    if stochastic:
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
+
+
 @pytest.mark.parametrize("quant", [False, True])
 @pytest.mark.parametrize("right", [False, True])
 def test_fused_apply_kernels(quant, right):
@@ -407,6 +451,77 @@ def test_fused_apply_train_step_matches_chain():
                                  jax.tree_util.tree_leaves_with_path(pb)):
         np.testing.assert_allclose(np.asarray(xa), np.asarray(xb),
                                    rtol=2e-5, atol=2e-6, err_msg=str(ka))
+
+
+def test_full_shape_int8_leaves_route_by_shape(monkeypatch):
+    """With `fused_adam` a full-shape int8 leaf runs the epilogue's fold
+    when its last dim is whole 128-lane rows, a Pallas backend is selected
+    and no multi-device mesh is active; every other case, and every leaf
+    with `fused_adam` off, keeps the XLA codec bit for bit."""
+    from repro.kernels import galore_fused as F
+    from repro.utils import ShardingRules, sharding_context
+
+    key = jax.random.PRNGKey(36)
+    shapes = {"embed": (200, 256), "embed_odd": (64, 72), "kv": (3, 16, 256),
+              "wide": (48, 130), "bias": (130,)}
+    params = {k: jax.random.normal(jax.random.fold_in(key, i), s)
+              for i, (k, s) in enumerate(shapes.items())}
+    grads = _grads(params, key)
+    cfg = GaLoreConfig(rank=16, quant=QuantPolicy(moments="int8",
+                                                  min_quant_size=1000))
+    plans = plan_for_params(params, cfg)
+    assert {k for k, plan in plans.items() if plan.galore} == {"wide"}
+
+    def routes_and_update(fused, tree=params):
+        opt = galore(scale_by_adam(), cfg, fused_adam=fused, **HP)
+        g = {k: grads[k] for k in tree}
+        with ops.record_routes() as routes:
+            out = opt.update(g, opt.init(tree), tree)
+        return sorted(routes), out
+
+    # no Pallas backend (the CPU): the XLA codec, as with fused_adam off
+    routes, on = routes_and_update(True)
+    assert routes == [("full", (3, 16, 256), "reference"),
+                      ("full", (64, 72), "reference"),
+                      ("full", (200, 256), "reference"),
+                      ("left", (48, 130), "reference")]
+    routes, off = routes_and_update(False)
+    assert routes == []
+    for a, b in zip(jax.tree_util.tree_leaves(on), jax.tree_util.tree_leaves(off)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # a Pallas backend (kernel bodies interpreted): lane-aligned leaves fold
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    call = F._fused_epilogue_call
+    monkeypatch.setattr(F, "_fused_epilogue_call",
+                        lambda *a, **kw: call(*a, **{**kw, "interpret": True}))
+    routes, fold = routes_and_update(True)
+    assert routes == [("full", (3, 16, 256), "fused"),
+                      ("full", (64, 72), "reference"),
+                      ("full", (200, 256), "fused"),
+                      ("left", (48, 130), "fused")]
+    for k in ("embed", "kv"):
+        st_f, st_x = fold[1]["inner"], off[1]["inner"]
+        _check([fold[0][k], st_f["m"][k]["q"], st_f["m"][k]["scale"],
+                st_f["v"][k]["q"], st_f["v"][k]["scale"]],
+               [off[0][k], st_x["m"][k]["q"], st_x["m"][k]["scale"],
+                st_x["v"][k]["q"], st_x["v"][k]["scale"]], k)
+    routes, off_pallas = routes_and_update(False)
+    assert routes == []
+    full_leaves = lambda out: [(out[0][k], out[1]["inner"][mv][k])
+                               for k in ("embed", "embed_odd", "kv", "bias")
+                               for mv in ("m", "v")]
+    for a, b in zip(jax.tree_util.tree_leaves(full_leaves(off_pallas)),
+                    jax.tree_util.tree_leaves(full_leaves(off))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # inside a multi-device mesh the full-shape leaves keep the XLA codec
+    full = {k: params[k] for k in ("embed", "kv")}
+    mesh = jax.sharding.AbstractMesh((4,), ("data",))
+    with sharding_context(ShardingRules(mesh=mesh, rules={})):
+        routes, _ = routes_and_update(True, full)
+    assert routes == [("full", (3, 16, 256), "reference"),
+                      ("full", (200, 256), "reference")]
 
 
 def test_adam8bit_galore_routes_through_quant_subsystem():

@@ -6,12 +6,14 @@ more VMEM than a kernel may use, operations it cannot lower. These cases
 compile the kernels the LLaMA-1B GaLore step dispatches to (rank 512, leaf
 shapes 2048×2048, 2048×5461 and 5461×2048, two stacked layers) for a
 described v5e chip — no chip is needed — and check that the compiled
-program holds the kernel; two more compile the GaLore-ZeRO form for a
+program holds the kernel, as do the full-shape int8 fold at Qwen2-7B's
+embedding and stacked K/V widths; two more compile the GaLore-ZeRO form for a
 described four-chip v5e:2x2, where each owner runs the kernel on its own
 rank block. They do not run anything.
 """
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -121,6 +123,15 @@ def _case(name, sh):
                     *a, use_pallas=True),
                 (_sds(sh, (L, n, r), f32), _sds(sh, (L, m, n), bf16),
                  codes, sc, codes, sc, cnt))
+    if name.startswith("fold_"):
+        # full-shape int8 Adam at Qwen2-7B widths: the 38,144 x 3,584 tied
+        # embedding, the 7 stacked 3,584 x 512 K (or V) projections and a
+        # stack of 7 norm scales (or q biases)
+        shape = {"fold_embedding_38144x3584": (38144, 3584),
+                 "fold_kv_7x3584x512": (7, 3584, 512),
+                 "fold_norm_7x3584": (7, 3584)}[name]
+        return (F.adam8_axis_update,
+                (_sds(sh, shape, bf16), *_int8_moments(sh, shape, -1), cnt))
     if name == "project_5461x2048":
         return (GP.galore_project,
                 (_sds(sh, (L, 5461, R), f32), _sds(sh, (L, 5461, 2048), bf16)))
@@ -138,6 +149,9 @@ def _case(name, sh):
     "int8_int4p_right_5461x2048",
     "apply_int8_int4p_left_2048x5461",
     "int8_right_rank32_lane_padded",
+    "fold_embedding_38144x3584",
+    "fold_kv_7x3584x512",
+    "fold_norm_7x3584",
     "project_5461x2048",
     "project_back_5461x2048",
 ])
@@ -145,6 +159,38 @@ def test_kernel_compiles_for_v5e(v5e_devices, name):
     fn, args = _case(name, SingleDeviceSharding(v5e_devices[0]))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fold_call_is_not_read_as_a_galore_kernel_call(v5e_devices):
+    """`galore_kernel_roofline` counts the calls that bench/trace.py's
+    `galore_call` reads as fused GaLore calls off their compiled operand
+    shapes. The full-shape int8 fold's call (codes of G's shape) must not
+    read as one, at the stacked K/V widths of Qwen2-7B; a GaLore int8 call
+    compiled beside it still does."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.trace import galore_call, parse_hlo
+
+    sh = SingleDeviceSharding(v5e_devices[0])
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    kv, (m, n) = (7, 3584, 512), (256, 1024)
+
+    def both(Gk, Mk, Sk, P, G, Mg, Sg, cnt):
+        fold = F.adam8_axis_update(Gk, Mk, Sk, Mk, Sk, cnt)
+        galore = F.galore_fused_adam8_step(P, G, Mg, Sg, Mg, Sg, cnt)
+        return fold, galore
+
+    text = jax.jit(both).lower(
+        _sds(sh, kv, bf16), _sds(sh, kv, jnp.uint8),
+        _sds(sh, kv[:2] + (kv[2] // 128,), f32),
+        _sds(sh, (L, m, R), f32), _sds(sh, (L, m, n), bf16),
+        _sds(sh, (L, R, n), jnp.uint8), _sds(sh, (L, R, n // 128), f32),
+        _sds(sh, (), jnp.int32)).compile().as_text()
+    calls = [info for info in parse_hlo(text).values()
+             if info["custom"] and info["target"] == "tpu_custom_call"]
+    read = sorted((galore_call(info) for info in calls), key=str)
+    assert len(calls) == 2 and read == [("left", L, m, n, R), None], read
 
 
 @pytest.mark.parametrize("side", ["left", "right"])
