@@ -1,0 +1,2 @@
+"""`model_type` qwen2: the dense decoder (bench/arch/dense.py)."""
+from bench.arch.dense import *  # noqa: F401,F403

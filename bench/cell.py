@@ -2,8 +2,13 @@
 
 Everything a cell needs is found by name: the workload entry in
 BENCHMARK.json names a configuration (bench/configs/<config>.json) and a
-traffic mix (bench/traffic/<traffic>.json). Nothing here knows a cell by
-name, so a later cell adds files and entries and no code.
+traffic mix (bench/traffic/<traffic>.json), and the configuration's
+published `model_type` names the module of its architecture
+(bench/arch/<model_type>.py).
+Nothing here knows a cell or an architecture by name: a later cell adds
+files and entries, and a configuration of an architecture the benchmark
+does not run yet adds its module under bench/arch/ as a new file. No file
+of the harness is edited for either.
 """
 from __future__ import annotations
 
@@ -11,19 +16,9 @@ import dataclasses
 import json
 import os
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from bench import arch
 
-# published config.json keys -> ModelConfig fields of the program
-MODEL_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "num_hidden_layers": "n_layers",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "head_dim": "head_dim",
-}
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _load(path: str) -> dict:
@@ -43,11 +38,6 @@ class Cell:
     config: dict  # the configuration file as committed
     traffic_name: str
     traffic: dict
-
-    @property
-    def head_dim(self) -> int:
-        c = self.config
-        return int(c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"])
 
     @property
     def rows(self) -> int:
@@ -70,6 +60,7 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     configs = {c["name"]: c for c in bench["configs"]}
     cfg_entry = configs[w["config"]]
     config = _load(os.path.join(root, cfg_entry["file"]))
+    arch.of(config)  # a model_type that no module implements fails here
     traffic = _load(os.path.join(root, "bench", "traffic", f"{w['traffic']}.json"))
     return Cell(workload, int(w["chips"]), w["config"], config, w["traffic"],
                 traffic)
@@ -77,12 +68,11 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
 
 def model_config(cell: Cell):
     """The program's ModelConfig for the cell: the published widths of the
-    configuration file and its `assumed` model settings."""
+    configuration file, as its architecture's module reads them, and its
+    `assumed` model settings."""
     from repro.configs.base import ModelConfig
 
-    c = cell.config
-    kw = {field: c[key] for key, field in MODEL_KEYS.items() if key in c}
-    kw.setdefault("head_dim", cell.head_dim)
+    kw = arch.of(cell.config).model_fields(cell.config)
     kw.update(cell.config["assumed"]["model"])
     return ModelConfig(name=cell.config_name, **kw)
 

@@ -7,6 +7,13 @@ Numbers, each with a limit of its own (bench/limits/<workload>.json):
 - grad: the first gradient as the optimizer got it, by the worst leaf and
   layer: |‖g_prog‖ − ‖g_ref‖| over the reference's norm of that leaf or of
   the median leaf, whichever is larger;
+- grad_elem: the same gradient element by element, for the leaves that take
+  full-shape 8-bit Adam (the embedding, K/V, biases, norm scales), by the
+  worst leaf and layer: ‖g_prog − g_ref‖ over the same norm. A gap of norms
+  averages rounding away; this one sees a gradient computed in a lower
+  precision. The GaLore leaves are left out of it: the optimizer holds
+  their gradient projected on its own subspace, which is not the
+  reference's;
 - change_galore, change_adam: the same gap of the norms of each leaf's
   change over the first steps, by the worst leaf and layer of each group:
   the leaves that move in a GaLore subspace, and those that take full-shape
@@ -27,9 +34,26 @@ import json
 import os
 import statistics
 
+import jax
+import jax.numpy as jnp
+
 from bench.cell import ROOT
 
 ROUNDING_FLOOR = 1e-3  # of the median leaf's first gradient
+
+
+@jax.jit
+def _diff_norm(a, b):
+    return jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32) - b.astype(jnp.float32))))
+
+
+def grad_elem_gaps(prog: dict, ref: dict) -> dict:
+    """{(path, instance): ‖g_prog − g_ref‖ / max(‖g_ref‖, median leaf's)} of
+    every leaf whose whole first gradient the reference keeps."""
+    med = statistics.median(ref["grad_norms"].values())
+    return {k: float(_diff_norm(prog["first_grads"][k], g))
+            / max(ref["grad_norms"][k], med)
+            for k, g in ref["first_grads"].items()}
 
 
 def _gaps(prog: dict, ref: dict, keys, med: float) -> dict:
@@ -60,8 +84,8 @@ def groups(ref: dict) -> dict:
 
 def numbers(prog: dict, ref: dict) -> dict:
     """{name: (value, where)} of the compared numbers; `prog` holds
-    "losses", "grad_norms" and "change_norms", `ref` the reference's
-    readings (bench/reference/galore.py::run)."""
+    "losses", "grad_norms", "first_grads" and "change_norms", `ref` the
+    reference's readings (bench/reference/galore.py::run)."""
     out = {}
     for i, (p, r) in enumerate(zip(prog["losses"], ref["losses"])):
         out[f"loss{i + 1}"] = (abs(p - r) / abs(r), f"step {i + 1}")
@@ -69,6 +93,8 @@ def numbers(prog: dict, ref: dict) -> dict:
     med = statistics.median(ref["grad_norms"].values())
     grad = _gaps(prog["grad_norms"], ref["grad_norms"], keys, med)
     out["grad"] = _worst(grad, keys)
+    elem = grad_elem_gaps(prog, ref)
+    out["grad_elem"] = _worst(elem, list(elem))
     grp = groups(ref)
     resolved = grp["galore"] + grp["adam"]
     med = statistics.median(ref["change_norms"][k] for k in resolved)

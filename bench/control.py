@@ -1,6 +1,7 @@
 """Readings that set a cell's limits, on the chip at the cell's own size.
 
-    python3 bench/control.py --workload <cell> --seeds 1 2 3 [--program] [--out PATH]
+    python3 bench/control.py --workload <cell> --seeds 1 2 3 [--program]
+        [--rows control_fp8 half_batch ...] [--out PATH]
 
 For each seed, against the float32 reference of the first steps: the
 reference in float8 put in the program's place (the control), and the
@@ -9,8 +10,9 @@ embedding's update left out, the second moment of the full-shape Adam
 leaves read four times too large. With --program the program's own first
 steps (a window of none) are read too, from the same process. Prints one
 JSON line per seed: each number of bench/check.py, and the verdict of each
-row under the committed limits (bench/limits/<cell>.json). With --out, also
-appends per-leaf norms of the program and the reference to PATH. The limits
+row under the committed limits (bench/limits/<cell>.json). --rows reads only
+the rows it names. With --out, also appends per-leaf norms of the program
+and the reference, and each row's per-leaf `grad_elem` gaps, to PATH. The limits
 are set from these readings (lower: the program's largest over seeds;
 upper: the least the control or a fault gives). The benchmark's own runs do
 not run this.
@@ -18,6 +20,7 @@ not run this.
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import os
 import sys
@@ -49,6 +52,8 @@ def main(argv=None):
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--program", action="store_true")
+    ap.add_argument("--rows", nargs="*", default=list(ROWS), choices=list(ROWS),
+                    help="which of the control and the faults to read (all)")
     ap.add_argument("--out", default=None, metavar="PATH")
     args = ap.parse_args(argv)
 
@@ -59,6 +64,8 @@ def main(argv=None):
     cell = load_cell(args.workload)
     lim = check.limits(cell.name)
     run.setup_jax()
+    import jax
+
     devices = run.require_chips(cell.chips)
     for seed in args.seeds:
         row = {"seed": seed}
@@ -70,18 +77,30 @@ def main(argv=None):
                                  cell.rows, seed)
             batches = [stream.batch(s) for s in range(run.CHECK_STEPS)]
         ref = run.reference_readings(cell, seed, batches)
+        # to the host: each row below runs a whole reference of its own, which
+        # needs the chip's memory as the benchmark's single reference has it
+        ref["first_grads"] = jax.device_get(ref["first_grads"])
         if args.program:
             row["program"] = _row(check.numbers(prog, ref), lim)
-        for name, kw in ROWS.items():
-            kw = {k: (cell.rows // 2 if v == "half" else v) for k, v in kw.items()}
-            row[name] = _row(check.numbers(
-                run.reference_readings(cell, seed, batches, **kw), ref), lim)
+        elem = {}
+        if args.program:
+            elem["program"] = check.grad_elem_gaps(prog, ref)
+        for name in args.rows:
+            kw = {k: (cell.rows // 2 if v == "half" else v)
+                  for k, v in ROWS[name].items()}
+            other = run.reference_readings(cell, seed, batches, **kw)
+            row[name] = _row(check.numbers(other, ref), lim)
+            elem[name] = check.grad_elem_gaps(other, ref)
+            del other
+            gc.collect()
         print(json.dumps(row), flush=True)
         if args.out:
             grp = check.groups(ref)
             detail = {"seed": seed, "groups": {k: [f"{p}[{l}]" for p, l in v]
                                                for k, v in grp.items()},
                       "reference": _leaves(ref, sum(grp.values(), [])),
+                      "grad_elem": {name: {f"{p}[{l}]": v for (p, l), v in g.items()}
+                                    for name, g in elem.items()},
                       "first_step_rms": {f"{p}[{l}]": v for (p, l), v
                                          in ref["first_step_rms"].items()},
                       "w0_half_ulp_rms": {f"{p}[{l}]": v for (p, l), v
@@ -92,6 +111,9 @@ def main(argv=None):
                 detail["reference_losses"] = ref["losses"]
             with open(args.out, "a") as f:
                 f.write(json.dumps(detail) + "\n")
+        # nothing of this seed stays on the chip while the next one runs
+        prog = ref = None
+        gc.collect()
 
 
 if __name__ == "__main__":

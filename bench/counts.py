@@ -1,8 +1,10 @@
 """Operations and bytes the work needs, computed from shapes.
 
-The yardstick of the utilisation metrics: a model's FLOPs per trained
-token, and a fused GaLore kernel call's matrix FLOPs and HBM bytes. They are
-kept with the benchmark so that a change to the program cannot move them.
+The yardstick of the utilisation metrics: the attention term of a model's
+FLOPs per trained token (each architecture counts the rest in its module
+under bench/arch/), and a fused GaLore kernel call's matrix FLOPs and HBM
+bytes. They are kept with the benchmark so that a change to the program
+cannot move them.
 """
 from __future__ import annotations
 
@@ -10,31 +12,12 @@ F32 = 4
 QBLOCK = 128  # block of the int8 moments' and int4 projectors' scales
 
 
-def matmul_params(config: dict) -> int:
-    """Weights that take part in a matrix product per token: every layer's
-    projections and the tied output head over the vocabulary slice (the
-    embedding lookup is a gather and is not counted)."""
-    d = config["hidden_size"]
-    H = config["num_attention_heads"]
-    KV = config["num_key_value_heads"]
-    hd = config.get("head_dim") or d // H
-    f = config["intermediate_size"]
-    per_layer = d * H * hd * 2 + d * KV * hd * 2 + 3 * d * f
-    return config["num_hidden_layers"] * per_layer + config["vocab_size"] * d
-
-
-def attention_flops_per_token(config: dict, seq_len: int) -> int:
-    """PaLM's count (Chowdhery et al. 2022, appendix B): 12 · layers ·
-    heads · head size · sequence length, forward and backward."""
-    H = config["num_attention_heads"]
-    hd = config.get("head_dim") or config["hidden_size"] // H
-    return 12 * config["num_hidden_layers"] * H * hd * seq_len
-
-
-def model_flops_per_token(config: dict, seq_len: int) -> int:
-    """Forward and backward FLOPs per trained token: 6 per matmul weight
-    plus attention, with no recomputation counted."""
-    return 6 * matmul_params(config) + attention_flops_per_token(config, seq_len)
+def attention_flops_per_token(layers: int, heads: int, head_dim: int,
+                              seq_len: int) -> int:
+    """PaLM's count (Chowdhery et al. 2022, appendix B) for causal softmax
+    attention: 12 · layers · heads · head size · sequence length, forward
+    and backward."""
+    return 12 * layers * heads * head_dim * seq_len
 
 
 def galore_kernel(side: str, layers: int, m: int, n: int, rank: int,

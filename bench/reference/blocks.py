@@ -1,21 +1,16 @@
-"""Plain float32 reference of a dense decoder: forward, loss and gradients.
+"""Float32 building blocks of the plain references (bench/arch/).
 
-Written from the published description of the Llama-style decoders the
-benchmark runs (InternLM2, Qwen2): RMSNorm, rotary embeddings on half
-rotation, grouped-query causal attention with optional q/k/v bias, a SwiGLU
-MLP, and the output head tied to the embedding over the vocabulary slice
-(the program's departure, see the configuration's `departures`). Nothing
-here imports the program.
+Written from the published descriptions of the models the benchmark runs:
+RMSNorm, rotary embeddings on half rotation, grouped-query causal attention
+with optional q/k/v bias, a SwiGLU MLP, and the output head tied to the
+embedding over the vocabulary slice (the program's departure, see the
+configuration's `departures`). Nothing here imports the program.
 
 Matrix products run at "highest" precision, or, for the control, with both
 operands rounded to float8 (e4m3, one scale per tensor) in the forward and
-the backward pass. One layer is traced at a time, so that a whole stage
-fits beside its state: the caller keeps the layer inputs and walks the
-layers back.
+the backward pass.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -102,14 +97,6 @@ def mlp(w: dict, x, precision):
     return matmul(h, w["down"], precision)
 
 
-def layer(c: dict, w: dict, x, precision: str = "f32"):
-    """One decoder layer; w holds the layer's leaves by short name (wq, wk,
-    wv, wo, [bq, bk, bv], ln1, ln2, gate, up, down)."""
-    eps = c["rms_norm_eps"]
-    x = x + attention(c, w, rms_norm(x, w["ln1"], eps), precision)
-    return x + mlp(w, rms_norm(x, w["ln2"], eps), precision)
-
-
 def head_loss(c: dict, final_scale, emb, x, batch, precision: str = "f32"):
     """Mean next-token cross entropy over the loss mask, with the logits of
     the tied head over the vocabulary slice (pad rows masked out)."""
@@ -121,58 +108,3 @@ def head_loss(c: dict, final_scale, emb, x, batch, precision: str = "f32"):
     tgt = jnp.take_along_axis(logits, batch["targets"][..., None], axis=-1)[..., 0]
     mask = batch["loss_mask"]
     return jnp.sum((logz - tgt) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-
-
-def _key(c: dict) -> tuple:
-    keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "rope_theta", "rms_norm_eps", "vocab_size")
-    return tuple((k, c[k]) for k in keys if k in c)
-
-
-@functools.lru_cache(maxsize=None)
-def _programs(c_key: tuple, precision: str):
-    c = dict(c_key)
-
-    @jax.jit
-    def fwd(w, x):
-        return layer(c, w, x, precision)
-
-    @jax.jit
-    def bwd(w, x, dy):
-        _, vjp = jax.vjp(lambda w_, x_: layer(c, w_, x_, precision), w, x)
-        return vjp(dy)
-
-    @jax.jit
-    def head(final_scale, emb, x, batch):
-        return jax.value_and_grad(
-            lambda s, e, x_: head_loss(c, s, e, x_, batch, precision),
-            argnums=(0, 1, 2))(final_scale, emb, x)
-
-    @jax.jit
-    def embed_grad(d_emb, tokens, dx0):
-        return d_emb.at[tokens.reshape(-1)].add(dx0.reshape(-1, dx0.shape[-1]))
-
-    return fwd, bwd, head, embed_grad
-
-
-def loss_and_grads(c: dict, weights, batch, on_layer, precision: str = "f32"):
-    """Loss and gradients of a stage, one layer at a time.
-
-    `weights.embed()`, `weights.final_scale()` and `weights.layer(l)` give
-    float32 arrays; `on_layer(l, dw)` takes each layer's gradients as they
-    come, last layer first, so that no more than one layer's are alive.
-    Returns (loss, d_final_scale, d_embed)."""
-    fwd, bwd, head, embed_grad = _programs(_key(c), precision)
-    emb = weights.embed()
-    x = jnp.take(emb, batch["tokens"], axis=0)
-    xs = []
-    for l in range(c["num_hidden_layers"]):
-        xs.append(x)
-        x = fwd(weights.layer(l), x)
-    loss, (d_scale, d_emb, dx) = head(weights.final_scale(), emb, x, batch)
-    del emb
-    for l in reversed(range(len(xs))):
-        dw, dx = bwd(weights.layer(l), xs.pop(), dx)
-        on_layer(l, dw)
-    d_emb = embed_grad(d_emb, batch["tokens"], dx)
-    return loss, d_scale, d_emb

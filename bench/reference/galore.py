@@ -9,16 +9,18 @@ the program's storage tricks:
   (embeddings excluded), P = its top-`rank` singular vectors of the shorter
   side, found by subspace iteration with an oversampled sketch and a
   Rayleigh-Ritz step; R = PᵀG (or G P), Adam on R in float32, and the update
-  scale · P N back at full shape;
+  scale · P N back at full shape. The matrix is an instance's last two axes:
+  an instance with leading axes of its own (experts) has a P for each;
 - every other leaf: Adam at full shape in float32;
 - the learning rate of the configuration's warm-up / cosine schedule.
 
 A GaLore weight moves only inside span(P) while P holds, so it is kept as
 W0 + P·C with C of the compact shape: W0 is made again from the seed, one
-layer at a time, and the compact state (P and the clipped projected
+instance at a time, and the compact state (P and the clipped projected
 gradient of each step) is all that is stored. That is the same arithmetic
 as a full-shape float32 weight, and it lets a 6144-wide stage run its
-reference on the chip that trained it.
+reference on the chip that trained it. The model itself, its forward pass,
+loss and gradients, is the architecture's (bench/arch/).
 """
 from __future__ import annotations
 
@@ -30,28 +32,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import arch
 from bench import weights as bw
-from bench.reference import model
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
-SHORT = {
-    "blocks/attn/wq": "wq", "blocks/attn/wk": "wk", "blocks/attn/wv": "wv",
-    "blocks/attn/wo": "wo", "blocks/attn/bq": "bq", "blocks/attn/bk": "bk",
-    "blocks/attn/bv": "bv", "blocks/ln1/scale": "ln1",
-    "blocks/ln2/scale": "ln2", "blocks/ffn/gate": "gate",
-    "blocks/ffn/up": "up", "blocks/ffn/down": "down",
-}
-PATH = {v: k for k, v in SHORT.items()}
 SUBSPACE_ITERS = 4  # power iterations of the reference's subspace search
 
 
 def side(path: str, shape: tuple, rank: int):
     """"left" (P spans the columns, m <= n), "right", or None for a leaf
-    that takes full-shape Adam."""
-    if "embed" in path or len(shape) != 2 or min(shape) <= rank:
+    that takes full-shape Adam; `shape` is one instance's, whose last two
+    axes (m, n) are the matrix."""
+    if "embed" in path or len(shape) < 2 or min(shape[-2:]) <= rank:
         return None
-    return "left" if shape[0] <= shape[1] else "right"
+    return "left" if shape[-2] <= shape[-1] else "right"
 
 
 def schedule(train: dict, count: int) -> float:
@@ -84,21 +79,38 @@ def top_subspace(A, rank: int, key):
     return jnp.matmul(Q, top, precision=HIGHEST)
 
 
+def projector(G, left: bool, rank: int, key):
+    """P of an instance's gradient G (..., m, n): one top subspace of the
+    shorter side for each matrix of the leading axes, each from its own key."""
+    A = G if left else jnp.swapaxes(G, -1, -2)
+    lead = A.shape[:-2]
+    if not lead:
+        return top_subspace(A, rank, key)
+    flat = A.reshape((-1,) + A.shape[-2:])
+    P = jnp.stack([top_subspace(a, rank, jax.random.fold_in(key, j))
+                   for j, a in enumerate(flat)])
+    return P.reshape(lead + P.shape[-2:])
+
+
 @jax.jit
 def _sq(x):
     return jnp.sum(jnp.square(x.astype(F32)))
 
 
+def _t(x):
+    return jnp.swapaxes(x, -1, -2)
+
+
 @functools.partial(jax.jit, static_argnums=(2,))
 def _project(P, G, left: bool):
-    return (jnp.matmul(P.T, G, precision=HIGHEST) if left
+    return (jnp.matmul(_t(P), G, precision=HIGHEST) if left
             else jnp.matmul(G, P, precision=HIGHEST))
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
 def _expand(W0, P, C, left: bool):
     return W0.astype(F32) + (jnp.matmul(P, C, precision=HIGHEST) if left
-                             else jnp.matmul(C, P.T, precision=HIGHEST))
+                             else jnp.matmul(C, _t(P), precision=HIGHEST))
 
 
 @jax.jit
@@ -148,31 +160,32 @@ class GaloreReference:
         self.rank = a["galore"]["rank"]
         self.scale = a["galore"]["scale"]
         self.train = a["train"]
-        self.shapes = bw.leaf_shapes(config)
-        self.L = config["num_hidden_layers"]
+        self.arch = arch.of(config)
+        self.leaves = bw.leaves(config)
         self.t = 0
         self.lrs: list = []
-        self.P: dict = {}    # (path, layer) -> P
-        self.R: dict = {}    # (path, layer) -> [clipped R of each step]
-        self.full: dict = {}  # (path, layer) -> {"W", "m", "v"} float32
+        self.P: dict = {}    # (path, instance) -> P
+        self.R: dict = {}    # (path, instance) -> [clipped R of each step]
+        self.full: dict = {}  # (path, instance) -> {"W", "m", "v"} float32
         self.losses: list = []
         self.grad_norms: dict = {}  # first step, clipped, as the optimizer gets it
+        self.first_grads: dict = {}  # the same gradient of each full-shape leaf
         self.first_step_rms: dict = {}  # rms of each leaf's first update
         self.w0_half_ulp_rms: dict = {}
         cj = json.dumps(config, sort_keys=True)
         key = bw.seed_leaf_key(seed)
         self._w0 = {p: functools.partial(bw.leaf_fn(cj, p), key)
-                    for p in self.shapes}
+                    for p in self.leaves}
 
     # --- weights as the model reads them --------------------------------
     def side_of(self, path):
-        return side(path, self.shapes[path][0], self.rank)
+        return side(path, self.leaves[path].shape, self.rank)
 
-    def weight(self, path, layer):
-        key = (path, layer)
+    def weight(self, path, instance):
+        key = (path, instance)
         if key in self.full:
             return self.full[key]["W"]
-        w0 = self._w0[path](layer)
+        w0 = self._w0[path](instance)
         if key not in self.P:
             return w0.astype(F32)
         return _expand(w0, self.P[key], self.compact_change(key),
@@ -188,15 +201,6 @@ class GaloreReference:
             C = C - self.lrs[t - 1] * self.scale * N
         return C
 
-    def layer(self, l):
-        return {s: self.weight(p, l) for p, s in SHORT.items() if p in self.shapes}
-
-    def embed(self):
-        return self.weight("embed/embedding", 0)
-
-    def final_scale(self):
-        return self.weight("final_norm/scale", 0)
-
     # --- one optimizer step ---------------------------------------------
     def step(self, batch):
         if self.rows is not None:
@@ -205,28 +209,21 @@ class GaloreReference:
         pending: dict = {}
         sq = []
 
-        def take(path, layer, g):
-            key = (path, layer)
+        def take(path, instance, g):
+            key = (path, instance)
             sq.append(_sq(g))
             sd = self.side_of(path)
             if sd is None:
                 pending[key] = g
                 return
             if first:
-                A = g if sd == "left" else g.T
                 k = jax.random.fold_in(jax.random.PRNGKey(self.seed & 0xFFFFFFFF),
                                        len(self.P))
-                self.P[key] = top_subspace(A, self.rank, k)
+                self.P[key] = projector(g, sd == "left", self.rank, k)
             pending[key] = _project(self.P[key], g, sd == "left")
 
-        def on_layer(l, dw):
-            for s, g in dw.items():
-                take(PATH[s], l, g)
-
-        loss, d_scale, d_emb = model.loss_and_grads(self.c, self, batch,
-                                                    on_layer, self.precision)
-        take("final_norm/scale", 0, d_scale)
-        take("embed/embedding", 0, d_emb)
+        loss = self.arch.loss_and_grads(self.c, self.weight, batch, take,
+                                        self.precision)
         if first:
             for key in pending:
                 if key not in self.P:
@@ -249,6 +246,8 @@ class GaloreReference:
                     self.first_step_rms[key] = float(jnp.sqrt(
                         _sq(self.compact_change(key)) / w0.size))
                 continue
+            if first:
+                self.first_grads[key] = g
             st = self.full[key]
             if st is None:
                 w0 = self._w0[key[0]](key[1]).astype(F32)
@@ -267,7 +266,7 @@ class GaloreReference:
         self.losses.append(float(loss))
 
     def change_norms(self) -> dict:
-        """‖W_t − W_0‖ of every leaf and layer after the steps taken."""
+        """‖W_t − W_0‖ of every leaf and instance after the steps taken."""
         out = {}
         for key in self.P:
             out[key] = float(jnp.sqrt(_sq(self.compact_change(key))))
@@ -280,14 +279,16 @@ class GaloreReference:
 def run(config: dict, seed: int, batches, precision: str = "f32",
         rows: int | None = None, fault: str | None = None) -> dict:
     """Readings of the first len(batches) steps: per-step losses, the first
-    step's gradient as the optimizer gets it and the change of every leaf
-    and layer; with, for each leaf and layer, the root mean square of its
-    first update and of half a spacing of its initial weights' type, and
-    the leaves that move in a GaLore subspace."""
+    step's gradient as the optimizer gets it (its norm for every leaf and
+    instance, the whole of it for those that take full-shape Adam) and the
+    change of every leaf and instance; with, for each, the root mean square
+    of its first update and of half a spacing of its initial weights' type,
+    and the leaves that move in a GaLore subspace."""
     ref = GaloreReference(config, seed, precision, rows, fault)
     for b in batches:
         ref.step(b)
     return {"losses": ref.losses, "grad_norms": ref.grad_norms,
+            "first_grads": ref.first_grads,
             "change_norms": ref.change_norms(),
             "first_step_rms": ref.first_step_rms,
             "w0_half_ulp_rms": ref.w0_half_ulp_rms,

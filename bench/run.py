@@ -72,6 +72,12 @@ def require_chips(n: int):
     return devices[:n]
 
 
+def peak_bytes(devices) -> int:
+    """The most bytes any of the chips has held at once in this process."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
+
+
 class CompileClock:
     """Counts the backend compiles JAX reports while it is installed."""
 
@@ -120,6 +126,7 @@ class Window:
         self.on_open = on_open
         self.losses: list = []
         self.grad_norms = None
+        self.first_moments = None
         self.change_norms = None
         self.t_open = None
         self.compiles_open = None
@@ -131,9 +138,26 @@ class Window:
         self.window_losses: list = []
         self.jitted = None
         self.phases = [("process start", T_START)]
+        self.peaks: list = []  # (phase, the fullest chip's peak bytes so far)
 
     def on_build(self, jitted):
         self.jitted = jitted
+
+    def watch_batches(self, batch):
+        """`batch`, which also reads the fullest chip's peak the first time
+        the loop asks for rows with its state built: before step 0's
+        subspace refresh and step."""
+        import jax
+
+        def watched(step):
+            if not self.peaks:
+                loop = _loop_locals()
+                if "opt_state" in loop:
+                    jax.block_until_ready((loop["params"], loop["opt_state"]))
+                    self.peaks.append(("state built", peak_bytes(jax.devices())))
+            return batch(step)
+
+        return watched
 
     def on_step(self, step, metrics):
         import jax
@@ -144,12 +168,17 @@ class Window:
             self.losses.append(float(metrics["loss"]))
             now = time.perf_counter()
             self.phases.append((f"step {step}", now))
+            self.peaks.append((f"step {step}", peak_bytes(jax.devices())))
             if step > 0:
                 self.step_est = now - self.prev_t
             if step == 0:
                 b1 = self.cell.config["assumed"]["train"]["b1"]
+                opt_state = _loop_locals()["opt_state"]
                 self.grad_norms = state.first_grad_norms(
-                    self.cell.config, _loop_locals()["opt_state"], b1)
+                    self.cell.config, opt_state, b1)
+                self.first_moments = state.first_moments(self.cell.config,
+                                                         opt_state)
+                del opt_state
                 self.phases.append(("first gradient read", time.perf_counter()))
             if step == CHECK_STEPS - 1:
                 self.change_norms = state.change_norms(
@@ -220,6 +249,7 @@ def drive(cell, seed: int, seconds: float, trace: bool, devices,
     """Set-up, window and readings of one run; the reference comes after."""
     import jax
 
+    from bench import state
     from bench.cell import train_configs
     import repro.launch.train as T
     from repro.launch import mesh as mesh_lib
@@ -238,6 +268,7 @@ def drive(cell, seed: int, seconds: float, trace: bool, devices,
     win.phases.append(("loop start", time.perf_counter()))
     try:
         with benchmark_inputs(cell, seed) as (cfg, stream):
+            stream.batch = win.watch_batches(stream.batch)
             params, opt_state, _, _ = T.train_loop(
                 run, tc, cfg=cfg, on_step=win.on_step, mesh=mesh,
                 on_build=win.on_build)
@@ -245,8 +276,7 @@ def drive(cell, seed: int, seconds: float, trace: bool, devices,
             compiles = clock.count - win.compiles_open
             if tracer is not None:
                 tracer.stop()
-            peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-                       for d in devices)
+            peak = peak_bytes(devices)
             hlo = None
             if tracer is not None:
                 hlo = win.jitted.lower(params, opt_state,
@@ -260,6 +290,7 @@ def drive(cell, seed: int, seconds: float, trace: bool, devices,
     steps = win.last - win.first + 1
     out = {
         "setup_phases": [(name, round(t - T_START, 3)) for name, t in win.phases],
+        "setup_peaks": win.peaks,
         "setup_compiles": (win.compiles_open, round(clock.seconds, 3)),
         "setup_s": win.t_open - T_START,
         "window_s": window_s,
@@ -270,6 +301,9 @@ def drive(cell, seed: int, seconds: float, trace: bool, devices,
         "losses": win.losses,
         "window_losses": win.window_losses,
         "grad_norms": win.grad_norms,
+        "first_grads": state.FirstGrads(
+            cell.config, win.first_moments,
+            cell.config["assumed"]["train"]["b1"]),
         "change_norms": win.change_norms,
         "batches": batches,
     }
@@ -368,7 +402,8 @@ def main(argv=None):
           f"losses {r['window_losses']}", file=sys.stderr)
     print(f"[bench] set-up phases (s from process start): {r['setup_phases']}; "
           f"compiles before the window (count, backend seconds): "
-          f"{r['setup_compiles']}", file=sys.stderr)
+          f"{r['setup_compiles']}; peak bytes after each set-up step: "
+          f"{r['setup_peaks']}", file=sys.stderr)
 
     from bench import check
 

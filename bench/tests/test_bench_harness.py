@@ -16,7 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from bench import counts, trace  # noqa: E402
+from bench import arch, counts, trace  # noqa: E402
 from bench.metrics import Context, load_reader  # noqa: E402
 
 
@@ -114,6 +114,72 @@ def test_busy_union_gaps_and_attribution():
     assert load_reader("galore_kernel_ms_per_step").read(ctx) == pytest.approx(70e-6)
 
 
+# an op in a named scope inside a fusion's computation, ops of one program
+# file in a scope and outside any
+HLO_SCOPES = """HloModule jit_pinned_step
+
+fused_computation.5 {
+  p.0 = f32[64]{0} parameter(0)
+  ROOT s.1 = s32[64]{0} compare(p.0, p.0), direction=LT, metadata={op_name="jit(pinned_step)/transpose(jvp())/while/body/closed_call/checkpoint/int8_quantize/searchsorted" source_file="/x/src/repro/quant/codec.py" source_line=3}
+}
+
+ENTRY main {
+  fusion.5 = s32[64]{0} fusion(a), kind=kLoop, calls=fused_computation.5
+  fusion.6 = f32[64]{0} fusion(b), kind=kLoop, calls=fused_computation.9, metadata={op_name="jit(pinned_step)/jvp()/moe/dot_general" source_file="/x/src/repro/models/moe.py" source_line=9}
+  fusion.7 = f32[64]{0} fusion(c), kind=kLoop, calls=fused_computation.9, metadata={op_name="jit(pinned_step)/jvp()/dot_general" source_file="/x/src/repro/models/moe.py" source_line=12}
+  copy.8 = f32[64]{0} copy(fusion.6)
+}
+"""
+
+
+def test_reduce_times_program_files_and_named_scopes():
+    step = "jit_pinned_step(1)"
+    ops = [("fusion.5", step, 0, 10, False), ("fusion.6", step, 10, 30, False),
+           ("fusion.7", step, 30, 60, False), ("copy.8", step, 60, 70, False),
+           ("fusion.5", "jit_other(2)", 70, 80, False)]
+    out = trace.reduce({"/device:TPU:0": ops}, [], trace.parse_hlo(HLO_SCOPES),
+                       window_s=100e-9)
+    assert out["files"] == {"quant/codec.py": pytest.approx(10e-9),
+                            "models/moe.py": pytest.approx(50e-9)}
+    assert out["scopes"] == {"int8_quantize": pytest.approx(10e-9),
+                             "moe": pytest.approx(20e-9)}
+    # the split by layer is as before: another program's op is unattributed
+    assert out["layers"] == {"optimizer": pytest.approx(10e-9),
+                             "model": pytest.approx(50e-9),
+                             "unattributed": pytest.approx(20e-9)}
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(f)/jvp()/while/body/closed_call/mix/int8_quantize/mul", "int8_quantize"),
+    ("jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/mix/dot_general", "mix"),
+    ("jit(f)/cond/branch_1_fun/moe/add", "moe"),
+    ("jit(f)/transpose(jvp())/while/body/add", None),
+    ("jit(pinned_step)/pallas_call", None),
+])
+def test_innermost_scope_passes_over_what_jax_writes(op_name, scope):
+    assert trace.innermost_scope(op_name) == scope
+
+
+@pytest.mark.parametrize("operands,call", [
+    # (L, m, n) with int4 projector codes: as the kernels of a dense stack
+    ([("u8", (6, 64, 32)), ("f32", (6, 1, 32)), ("bf16", (6, 128, 512)),
+      ("u8", (6, 32, 512)), ("f32", (6, 32, 4)), ("s32", ())],
+     ("left", 6, 128, 512, 32)),
+    ([("u8", (7, 256, 896)), ("bf16", (7, 18944, 3584)), ("u8", (7, 18944, 896)),
+      ("f32", (7, 18944, 7))], ("right", 7, 18944, 3584, 896)),
+    # (L, E, m, n): stacked experts, each (layer, expert) a matrix of its own
+    ([("u8", (2, 4, 64, 32)), ("bf16", (2, 4, 128, 512)), ("u8", (2, 4, 32, 512)),
+      ("f32", (2, 4, 32, 4))], ("left", 8, 128, 512, 32)),
+    ([("bf16", (2, 4, 512, 128)), ("u8", (2, 4, 512, 32)), ("f32", (2, 4, 512, 1))],
+     ("right", 8, 512, 128, 32)),
+    # the full-shape int8 fold: its codes have G's shape
+    ([("bf16", (7, 3584, 512)), ("u8", (7, 3584, 512)), ("f32", (7, 3584, 4)),
+      ("u8", (7, 3584, 512)), ("f32", (7, 3584, 4)), ("s32", ())], None),
+])
+def test_galore_call_reads_any_leading_axes(operands, call):
+    assert trace.galore_call({"custom": True, "operands": operands}) == call
+
+
 def test_readers_return_nothing_without_a_trace():
     ctx = Context(cell=None, peaks={}, run={"steps": 3}, n_chips=1)
     for name in ("device_idle_share", "model_ms_per_step", "optimizer_ms_per_step",
@@ -165,20 +231,23 @@ def test_kernel_bytes_match_the_real_operands():
 
 
 def test_model_flops_by_hand():
-    c = {"hidden_size": 8, "num_attention_heads": 2, "num_key_value_heads": 1,
-         "intermediate_size": 16, "num_hidden_layers": 3, "vocab_size": 10}
+    c = {"model_type": "qwen2", "hidden_size": 8, "num_attention_heads": 2,
+         "num_key_value_heads": 1, "intermediate_size": 16,
+         "num_hidden_layers": 3, "vocab_size": 10}
+    dense = arch.of(c)
     per_layer = 8 * 8 * 2 + 8 * 4 * 2 + 3 * 8 * 16
     n = 3 * per_layer + 10 * 8
-    assert counts.matmul_params(c) == n
-    assert counts.model_flops_per_token(c, 5) == 6 * n + 12 * 3 * 2 * 4 * 5
+    assert dense.matmul_params(c) == n
+    assert dense.flops_per_token(c, 5) == 6 * n + 12 * 3 * 2 * 4 * 5
 
 
 def test_internlm2_stage_flops():
     """39.8 TFLOP per 4096-token update for the InternLM2-20B stage (4 layers
     of 390.1 M matmul weights, a 7712-row head, 256-token attention)."""
-    c = {"hidden_size": 6144, "intermediate_size": 16384, "num_attention_heads": 48,
-         "num_key_value_heads": 8, "num_hidden_layers": 4, "vocab_size": 7712}
-    assert counts.model_flops_per_token(c, 256) * 4096 == pytest.approx(39.82e12, rel=1e-3)
+    c = {"model_type": "internlm2", "hidden_size": 6144, "intermediate_size": 16384,
+         "num_attention_heads": 48, "num_key_value_heads": 8, "num_hidden_layers": 4,
+         "vocab_size": 7712}
+    assert arch.of(c).flops_per_token(c, 256) * 4096 == pytest.approx(39.82e12, rel=1e-3)
 
 
 def test_bigram_map_is_one_cycle_through_the_vocabulary():
@@ -216,6 +285,8 @@ def test_token_rows_stay_c4_like():
 def test_change_is_compared_by_the_worst_leaf_of_each_group():
     """A leaf whose first update is under half a spacing of its weights' type
     is left out; each group is read by its worst leaf."""
+    import numpy as np
+
     from bench import check
 
     keys = [("w", 0), ("w", 1), ("emb", 0), ("scale", 0), ("kbias", 0)]
@@ -223,8 +294,10 @@ def test_change_is_compared_by_the_worst_leaf_of_each_group():
            "grad_norms": dict(zip(keys, [1.0, 1.0, 1.0, 1.0, 1e-6])),
            "first_step_rms": dict(zip(keys, [1e-4, 1e-4, 1e-3, 1e-3, 1e-3])),
            "w0_half_ulp_rms": dict(zip(keys, [6e-5, 6e-5, 6e-5, 3.9e-3, 0.0])),
-           "change_norms": dict(zip(keys, [1.0, 2.0, 4.0, 0.1, 0.1]))}
+           "change_norms": dict(zip(keys, [1.0, 2.0, 4.0, 0.1, 0.1])),
+           "first_grads": {("emb", 0): np.ones(4) / 2}}
     prog = {"losses": [1.0], "grad_norms": ref["grad_norms"],
+            "first_grads": ref["first_grads"],
             "change_norms": dict(zip(keys, [1.1, 2.0, 3.0, 0.0, 0.0]))}
     assert check.groups(ref) == {"galore": [("w", 0), ("w", 1)],
                                  "adam": [("emb", 0)], "unresolved": [("scale", 0)]}
@@ -234,6 +307,32 @@ def test_change_is_compared_by_the_worst_leaf_of_each_group():
     assert nums["change_unresolved"] == (pytest.approx(0.05), "scale[0]")
     ok, out = check.verdict(nums, {"change_galore": 0.1, "change_adam": 0.2})
     assert not ok and set(out) == {"change_galore", "change_adam"}
+
+
+def test_grad_is_also_compared_element_by_element():
+    """A gradient with the right norm and the wrong elements passes `grad`
+    and fails `grad_elem`; a leaf under the median is read against the
+    median leaf's norm."""
+    import numpy as np
+
+    from bench import check
+
+    keys = [("w", 0), ("emb", 0), ("bias", 0)]
+    g = np.array([3.0, -4.0, 0.0, 0.0])
+    ref = {"losses": [1.0], "grad_norms": dict(zip(keys, [5.0, 5.0, 0.5])),
+           "first_grads": {("emb", 0): g, ("bias", 0): np.array([0.3, 0.4])}}
+    prog = {"losses": [1.0], "grad_norms": ref["grad_norms"],
+            "first_grads": {("emb", 0): g[[1, 0, 2, 3]], ("bias", 0): np.array([0.4, 0.3])}}
+    gaps = check.grad_elem_gaps(prog, ref)
+    assert gaps[("emb", 0)] == pytest.approx(7 * 2 ** 0.5 / 5)
+    assert gaps[("bias", 0)] == pytest.approx(0.1 * 2 ** 0.5 / 5)  # over the median, 5
+    same = {"change_norms": dict.fromkeys(keys, 1.0)}
+    ref.update(same, galore=[], first_step_rms=dict.fromkeys(keys, 1.0),
+               w0_half_ulp_rms=dict.fromkeys(keys, 0.0))
+    nums = {k: v for k, v in check.numbers({**prog, **same}, ref).items()
+            if k.startswith("grad")}
+    assert nums == {"grad": (0.0, "w[0]"),
+                    "grad_elem": (pytest.approx(7 * 2 ** 0.5 / 5), "emb[0]")}
 
 
 def test_op_events_name_their_instruction():

@@ -1,11 +1,14 @@
-"""From a profiler trace of the window to device time by layer.
+"""From a profiler trace of the window to device time by layer, by source
+file and by named scope.
 
 The window's device operations come from the JAX profiler's trace (the
 "XLA Ops" lines of the TPU planes). Each operation is joined to the HLO
 instruction of the same name in the compiled train step, and through that
-instruction's metadata to the source file that emitted it; the file's
-directory names the layer (LAYERS). Device time that no rule claims, and
-the operations of other programs, go to `unattributed`.
+instruction's metadata to the source file that emitted it and to the
+innermost `jax.named_scope` of its `op_name`. The file's directory names
+the layer (LAYERS). Device time that no rule claims, and the operations of
+other programs, go to `unattributed`; a per-layer metric of one module or
+one scope reads `files` or `scopes`.
 """
 from __future__ import annotations
 
@@ -36,6 +39,7 @@ NS = 1e-9
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMP = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*\))?.*\{\s*$")
 _SOURCE = re.compile(r'source_file="([^"]*)"')
+_OP_NAME_META = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _ARRAY = re.compile(r"\b(pred|s8|u8|s16|u16|s32|u32|s64|u64|bf16|f16|f32|f64)"
                     r"\[([\d,]*)\]")
@@ -128,19 +132,47 @@ def _split(rest: str):
     return rtype, m.group(1), entries
 
 
+# components of an op_name that JAX itself writes: transformations hold a
+# parenthesis ("jit(f)", "transpose(jvp())"), control flow and remat these
+_JAX_NAMES = frozenset({"while", "body", "cond", "closed_call", "core_call",
+                        "checkpoint", "remat", "rematted_computation", "pjit",
+                        "scan", "shard_map", "custom_jvp_call", "custom_vjp_call"})
+_BRANCH = re.compile(r"^branch_\d+_fun$")
+
+
+def innermost_scope(op_name):
+    """The innermost `jax.named_scope` of an op_name
+    ("jit(step)/jvp()/while/body/int8_quantize/mul" -> "int8_quantize"), or
+    None: the last component, the primitive, and JAX's own are passed over."""
+    for part in reversed(op_name.split("/")[:-1]):
+        if part and "(" not in part and part not in _JAX_NAMES \
+                and not _BRANCH.match(part):
+            return part
+    return None
+
+
+def program_file(source):
+    """A source path relative to the program's package ("models/moe.py"),
+    or None outside it."""
+    if source and "/repro/" in source:
+        return source.rsplit("/repro/", 1)[1]
+    return None
+
+
 def parse_hlo(text: str) -> dict:
-    """{instruction name: {"source", "custom", "operands", "results"}}.
+    """{instruction name: {"source", "scope", "custom", "operands", "results"}}.
 
     `source` is the innermost file of the op's stack that lies in the
     program (src/repro/), else its innermost file: inline `source_file`
-    metadata, or the module's stack-frame tables. A fusion without a
-    source of its own takes the first found in the computation it calls.
+    metadata, or the module's stack-frame tables. `scope` is the innermost
+    named scope of its `op_name`. A fusion without a source or a scope of
+    its own takes the first found in the computation it calls.
     `operands` and `results` list the (dtype, shape) of a custom call's
     array operands and results; an operand printed by name alone takes
     the type of the instruction that defines it."""
     lines = text.splitlines()
     files, locs, frames = _tables(lines)
-    instrs, comp_source, comp = {}, {}, None
+    instrs, comp_source, comp_scope, comp = {}, {}, {}, None
     for line in lines:
         m = _INSTR.match(line)
         if m is None:
@@ -155,10 +187,16 @@ def parse_hlo(text: str) -> dict:
         if fid:
             stack += _frame_files(int(fid.group(1)), files, locs, frames)
         src = next((f for f in stack if "/repro/" in f), stack[0] if stack else None)
-        if src and comp is not None:
-            comp_source.setdefault(comp, src)
+        op = _OP_NAME_META.search(rest)
+        scope = innermost_scope(op.group(1)) if op else None
+        if comp is not None:
+            if src:
+                comp_source.setdefault(comp, src)
+            if scope:
+                comp_scope.setdefault(comp, scope)
         rtype, opcode, entries = _split(rest)
-        info = {"source": src, "type": rtype, "custom": opcode == "custom-call"}
+        info = {"source": src, "scope": scope, "type": rtype,
+                "custom": opcode == "custom-call"}
         calls = _CALLS.search(rest)
         if calls:
             info["calls"] = calls.group(1)
@@ -168,8 +206,11 @@ def parse_hlo(text: str) -> dict:
                               or [None, None])[1]
         instrs[name] = info
     for info in instrs.values():
-        if info["source"] is None and "calls" in info:
-            info["source"] = comp_source.get(info["calls"])
+        if "calls" in info:
+            if info["source"] is None:
+                info["source"] = comp_source.get(info["calls"])
+            if info["scope"] is None:
+                info["scope"] = comp_scope.get(info["calls"])
         if info["custom"]:
             info["results"] = _arrays(info["type"])
             info["operands"] = []
@@ -203,24 +244,33 @@ def layer_of(source) -> str:
     return "unattributed"
 
 
+def _size(shape) -> int:
+    n = 1
+    for d in shape:
+        n *= d
+    return n
+
+
 def galore_call(info: dict):
     """(side, layers, m, n, rank) of a fused GaLore kernel call, read off
-    its operands: G is the stacked bf16/f32 (L, m, n) weight gradient, the
-    int8 moments are (L, r, n) on the left and (L, m, r) on the right."""
+    its operands: G is the bf16/f32 (…, m, n) weight gradient, the int8
+    moments are (…, r, n) on the left and (…, m, r) on the right, over the
+    same leading axes (layers, experts), whose product is `layers`. The
+    full-shape int8 fold, whose codes have G's shape, is not one."""
     if not info.get("custom"):
         return None
-    floats = [s for dt, s in info["operands"] if dt in ("bf16", "f32") and len(s) == 3]
-    codes = [s for dt, s in info["operands"] if dt == "u8" and len(s) == 3]
+    floats = [s for dt, s in info["operands"] if dt in ("bf16", "f32") and len(s) >= 2]
     if not floats:
         return None
-    L, m, n = max(floats, key=lambda s: s[0] * s[1] * s[2])  # G, the largest
-    for c in codes:
-        if c[0] != L:
+    G = max(floats, key=_size)  # the largest
+    lead, (m, n) = G[:-2], G[-2:]
+    for c in (s for dt, s in info["operands"] if dt == "u8" and len(s) == len(G)):
+        if c[:-2] != lead:
             continue
-        if c[2] == n and c[1] != m:
-            return ("left", L, m, n, c[1])
-        if c[1] == m and c[2] != n:
-            return ("right", L, m, n, c[2])
+        if c[-1] == n and c[-2] != m:
+            return ("left", _size(lead), m, n, c[-2])
+        if c[-2] == m and c[-1] != n:
+            return ("right", _size(lead), m, n, c[-1])
     return None
 
 
@@ -310,13 +360,16 @@ def host_spans(pdata) -> list:
 
 def reduce(ops_by_device: dict, hosts: list, hlo: dict, window_s: float,
            module: str = "jit_pinned_step", top: int = 10) -> dict:
-    """Busy time, device time by layer, the GaLore kernel calls and the
-    breakdown, averaged over the devices traced. Ops of the train step's
-    program (`module`) are joined to its HLO; every other program's ops
-    are `unattributed`."""
+    """Busy time, device time by layer (`layers`), by program source file
+    (`files`, "models/moe.py") and by innermost named scope (`scopes`), the
+    GaLore kernel calls and the breakdown, averaged over the devices traced.
+    Ops of the train step's program (`module`) are joined to its HLO; every
+    other program's ops are `unattributed`, in no file and no scope."""
     n_dev = max(1, len(ops_by_device))
     busy = 0.0
     layers: dict = {}
+    files: dict = {}
+    scopes: dict = {}
     by_op: dict = {}
     kernels: dict = {}
     all_gaps = []
@@ -332,6 +385,12 @@ def reduce(ops_by_device: dict, hosts: list, hlo: dict, window_s: float,
                 layer = "kernels"
             dt = (e - s) * NS / n_dev
             layers[layer] = layers.get(layer, 0.0) + dt
+            if info:
+                f = program_file(info["source"])
+                if f:
+                    files[f] = files.get(f, 0.0) + dt
+                if info["scope"]:
+                    scopes[info["scope"]] = scopes.get(info["scope"], 0.0) + dt
             label = f"{name} ({layer})" if info else f"{name} ({mod or 'no program'})"
             by_op[label] = by_op.get(label, 0.0) + dt
             call = galore_call(info) if info else None
@@ -356,6 +415,8 @@ def reduce(ops_by_device: dict, hosts: list, hlo: dict, window_s: float,
         "busy_s": busy / n_dev,
         "window_s": window_s,
         "layers": layers,
+        "files": files,
+        "scopes": scopes,
         "kernels": list(kernels.values()),
         "breakdown": {"device_ops": [[k, v] for k, v in ops],
                       "idle_gaps": named},

@@ -1,13 +1,16 @@
-"""Weights of a dense decoder, made on the device from the seed.
+"""Weights of the program's parameter tree, made on the device from the seed.
 
-The tree is the one the program trains (`{"embed", "final_norm",
-"blocks": {"attn", "ln1", "ln2", "ffn"}}`, layers stacked on a leading
-axis). Every matrix of every layer is drawn from its own key, folded from
-the seed, the leaf's name and the layer, so the reference can make one
-layer of one leaf again, bit for bit, without the whole tree.
+The architecture's module (bench/arch/) lists the tree's leaves: for each
+path, the shape of one instance, its fan-in, how it is initialised and how
+many instances are stacked on a leading axis. Paths run through dict keys
+and through the indices of tuple nodes ("blocks/5/mix/wq"). Every instance
+of every leaf is drawn from its own key, folded from the seed, the leaf's
+path and the instance's index, so the reference can make one instance of
+one leaf again, bit for bit, without the whole tree.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import zlib
@@ -15,7 +18,26 @@ import zlib
 import jax
 import jax.numpy as jnp
 
+from bench import arch
 from bench.data import seed_key
+
+
+@dataclasses.dataclass(frozen=True)
+class Leaf:
+    """One leaf of the tree. `shape` is one instance's (a layer's weight,
+    with any leading axes of its own, such as experts); `init` is "normal"
+    (scaled by fan_in ** -0.5), "ones" or "zeros"; `stack` instances lie on
+    a leading axis, or the leaf is one instance with no such axis (0);
+    `dtype` None takes the configuration's."""
+    shape: tuple
+    fan_in: int | None
+    init: str
+    stack: int = 0
+    dtype: str | None = None
+
+    @property
+    def instances(self) -> int:
+        return self.stack or 1
 
 
 def padded_vocab(vocab: int) -> int:
@@ -23,32 +45,9 @@ def padded_vocab(vocab: int) -> int:
     return -(-int(vocab) // 256) * 256
 
 
-def leaf_shapes(config: dict) -> dict:
-    """{path: (shape of one layer, fan_in or None, kind, stacked)}; kind is
-    "normal", "ones" or "zeros"."""
-    d = config["hidden_size"]
-    H = config["num_attention_heads"]
-    KV = config["num_key_value_heads"]
-    hd = config.get("head_dim") or d // H
-    f = config["intermediate_size"]
-    out = {
-        "embed/embedding": ((padded_vocab(config["vocab_size"]), d), d, "normal", False),
-        "final_norm/scale": ((d,), None, "ones", False),
-        "blocks/attn/wq": ((d, H * hd), d, "normal", True),
-        "blocks/attn/wk": ((d, KV * hd), d, "normal", True),
-        "blocks/attn/wv": ((d, KV * hd), d, "normal", True),
-        "blocks/attn/wo": ((H * hd, d), H * hd, "normal", True),
-        "blocks/ln1/scale": ((d,), None, "ones", True),
-        "blocks/ln2/scale": ((d,), None, "ones", True),
-        "blocks/ffn/gate": ((d, f), d, "normal", True),
-        "blocks/ffn/up": ((d, f), d, "normal", True),
-        "blocks/ffn/down": ((f, d), f, "normal", True),
-    }
-    if config["assumed"]["model"].get("qkv_bias"):
-        out["blocks/attn/bq"] = ((H * hd,), None, "zeros", True)
-        out["blocks/attn/bk"] = ((KV * hd,), None, "zeros", True)
-        out["blocks/attn/bv"] = ((KV * hd,), None, "zeros", True)
-    return out
+def leaves(config: dict) -> dict:
+    """{path: Leaf} of the configuration's architecture."""
+    return arch.of(config).leaves(config)
 
 
 def dtype_of(config: dict):
@@ -56,22 +55,23 @@ def dtype_of(config: dict):
 
 
 def seed_leaf_key(seed: int):
-    """The key of the whole tree; each leaf and layer folds its own from it."""
+    """The key of the whole tree; each leaf and instance folds its own from it."""
     return seed_key(seed)
 
 
 def layer_leaf(config: dict, key, path: str, layer, dtype=None):
-    """One layer of one leaf (layer 0 for unstacked leaves), from the tree's
-    key; traceable in `key` and `layer`."""
-    shape, fan_in, kind, _ = leaf_shapes(config)[path]
-    dtype = dtype or dtype_of(config)
-    if kind == "ones":
-        return jnp.ones(shape, dtype)
-    if kind == "zeros":
-        return jnp.zeros(shape, dtype)
+    """One instance of one leaf (instance 0 of an unstacked leaf), from the
+    tree's key; traceable in `key` and `layer`."""
+    leaf = leaves(config)[path]
+    dtype = dtype or leaf.dtype or dtype_of(config)
+    if leaf.init == "ones":
+        return jnp.ones(leaf.shape, dtype)
+    if leaf.init == "zeros":
+        return jnp.zeros(leaf.shape, dtype)
     key = jax.random.fold_in(key, zlib.crc32(path.encode()))
     key = jax.random.fold_in(key, layer)
-    return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
+    return (jax.random.normal(key, leaf.shape, jnp.float32)
+            * leaf.fan_in ** -0.5).astype(dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -81,7 +81,8 @@ def leaf_fn(config_json: str, path: str):
     return jax.jit(lambda key, layer: layer_leaf(config, key, path, layer))
 
 
-def _nest(flat: dict) -> dict:
+def _nest(flat: dict):
+    """The tree of {"a/b/c": leaf}; a node whose keys are 0..n-1 is a tuple."""
     tree: dict = {}
     for path, v in flat.items():
         node = tree
@@ -89,30 +90,42 @@ def _nest(flat: dict) -> dict:
         for k in head:
             node = node.setdefault(k, {})
         node[last] = v
-    return tree
+
+    def build(node):
+        if not isinstance(node, dict):
+            return node
+        if node and sorted(node) == sorted(str(i) for i in range(len(node))):
+            return tuple(build(node[str(i)]) for i in range(len(node)))
+        return {k: build(v) for k, v in node.items()}
+
+    return build(tree)
 
 
 def init_params(config: dict, seed: int):
     """The whole tree, traceable (the program jits it onto its shardings)."""
-    L = config["num_hidden_layers"]
     key = seed_leaf_key(seed)
     flat = {}
-    for path, (_, _, _, stacked) in leaf_shapes(config).items():
-        if stacked:
+    for path, leaf in leaves(config).items():
+        if leaf.stack:
             flat[path] = jax.vmap(
-                lambda l, p=path: layer_leaf(config, key, p, l))(jnp.arange(L))
+                lambda l, p=path: layer_leaf(config, key, p, l))(jnp.arange(leaf.stack))
         else:
             flat[path] = layer_leaf(config, key, path, 0)
     return _nest(flat)
 
 
-def flat_paths(tree, prefix: str = "") -> dict:
-    """{"a/b/c": leaf} of a nested dict tree."""
+def flat_paths(tree, prefix: str = "", is_leaf=None) -> dict:
+    """{"a/0/c": leaf} of a tree of dicts, tuples and lists; a node for
+    which `is_leaf` holds is kept whole."""
+    if is_leaf is not None and is_leaf(tree):
+        return {prefix.rstrip("/"): tree}
+    if isinstance(tree, dict):
+        items = tree.items()
+    elif isinstance(tree, (tuple, list)):
+        items = enumerate(tree)
+    else:
+        return {prefix.rstrip("/"): tree}
     out = {}
-    for k, v in tree.items():
-        p = f"{prefix}{k}"
-        if isinstance(v, dict):
-            out.update(flat_paths(v, p + "/"))
-        else:
-            out[p] = v
+    for k, v in items:
+        out.update(flat_paths(v, f"{prefix}{k}/", is_leaf))
     return out
